@@ -73,12 +73,7 @@ def _compiles(agent: DreamShard) -> int:
     if agent.cfg.fused:
         return (agent._fused_cost_update.traces[0]
                 + agent._fused_rl_update.traces[0])
-    n = len(agent._rl_updates)
-    try:
-        n += agent._cost_update._cache_size()
-    except AttributeError:                        # older jax
-        n += 1
-    return n
+    return len(agent._rl_updates) + agent._cost_update._cache_size()
 
 
 def _run_variant(fused: bool, cfg: DreamShardConfig, train, test) -> dict:

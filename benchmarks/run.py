@@ -52,6 +52,8 @@ def main() -> None:
     mods = [args.only] if args.only else MODULES
 
     from repro import telemetry as tele
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     all_rows = {}
     with tele.trace_to(args.trace):

@@ -24,8 +24,8 @@ from repro.core.trainer import DreamShard, DreamShardConfig
 from repro.data.synthetic import make_dlrm_pool
 from repro.data.tasks import Task, make_benchmark_suite
 from repro.embedding import sharded as E
-from repro.models.dlrm import DLRM, DLRMConfig
-from repro.optim import adam, apply_updates, rowwise_adagrad
+from repro.models.dlrm import DLRM, DLRMConfig, make_train_step
+from repro.optim import adam, rowwise_adagrad
 
 
 def synth_batch(rng, plan, raw, batch, n_dense, pool_max=6):
@@ -39,8 +39,8 @@ def synth_batch(rng, plan, raw, batch, n_dense, pool_max=6):
         idx[:, t, :pools[t]] = draws
     dense = rng.normal(size=(batch, n_dense)).astype(np.float32)
     labels = (rng.random(batch) < 0.3).astype(np.float32)
-    return (jnp.asarray(E.group_indices(plan, idx)), jnp.asarray(dense),
-            jnp.asarray(labels))
+    return {"gidx": jnp.asarray(E.group_indices(plan, idx)),
+            "dense": jnp.asarray(dense), "labels": jnp.asarray(labels)}
 
 
 def train_with_placement(name, task, placement, args, oracle):
@@ -62,25 +62,14 @@ def train_with_placement(name, task, placement, args, oracle):
     def lookup(a, b, i):
         return E.lookup_unsharded(a, plan.base_rows, i, plan)
 
-    @jax.jit
-    def step(params, emb_state, dense_state, gidx, dense, labels):
-        def loss_fn(p):
-            return DLRM.loss(model.forward(p, dense, gidx, lookup), labels)
-        loss, g = jax.value_and_grad(loss_fn)(params)
-        eu, emb_state = emb_opt.update({"arenas": g["arenas"]}, emb_state)
-        du, dense_state = dense_opt.update(
-            {k: g[k] for k in ("bottom", "top")}, dense_state)
-        params = {**apply_updates({k: params[k] for k in ("bottom", "top")},
-                                  du),
-                  **apply_updates({"arenas": params["arenas"]}, eu)}
-        return params, emb_state, dense_state, loss
+    step = jax.jit(make_train_step(model, lookup, emb_opt, dense_opt))
 
     rng = np.random.default_rng(0)
     losses, t0 = [], time.perf_counter()
     for i in range(args.steps):
-        gidx, dense, labels = synth_batch(rng, plan, raw, args.batch, 13)
+        batch = synth_batch(rng, plan, raw, args.batch, 13)
         params, emb_state, dense_state, loss = step(
-            params, emb_state, dense_state, gidx, dense, labels)
+            params, emb_state, dense_state, batch)
         losses.append(float(loss))
         if i % max(args.steps // 5, 1) == 0:
             print(f"  [{name}] step {i:4d} loss {np.mean(losses[-20:]):.4f}")

@@ -9,8 +9,10 @@ part of the model sees every table's pooled embedding for its batch rows --
 the forward all-to-all of the paper; the transpose in the backward pass is
 the backward all-to-all.
 
-Inside the ``shard_map`` the lookup itself is the fused embedding-bag op
-(Pallas kernel on TPU, jnp oracle under transforms/CPU).
+Inside the ``shard_map`` the lookup is XLA's gather plus a masked pooled
+sum, on every backend.  The Pallas kernel (``repro.kernels.embedding_bag``)
+is not on this path; which of the two the step should use is open until
+both are measured on the chip.
 """
 
 from __future__ import annotations
@@ -21,29 +23,15 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-try:                                      # jax >= 0.6 top-level export
-    _shard_map = jax.shard_map
-    _REP_KWARG = "check_vma"
-except AttributeError:                    # older jax: experimental module
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _REP_KWARG = "check_rep"
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma=True):
-    """Version-robust shard_map (the replication-check kwarg was renamed)."""
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **{_REP_KWARG: check_vma})
-
-from repro.embedding.plan import PlacementPlan                # noqa: E402
-from repro.kernels.embedding_bag.ref import embedding_bag_ref  # noqa: E402
+from repro.embedding.plan import PlacementPlan
 
 
 def init_arenas(key, plan: PlacementPlan, dtype=jnp.float32,
                 scale: float = 0.01):
-    """(n_shards, rows_max, dim) stacked per-shard arenas."""
+    """(n_shards, rows_max, dim) stacked per-shard arenas.  Row 0 of each
+    is reserved: padded slots point at it, and the lookup masks them."""
     arenas = jax.random.normal(
         key, (plan.n_shards, plan.rows_max, plan.dim)) * scale
-    # zero rows stay zero via the lookup (padded slots point at row 0)
     return arenas.astype(dtype)
 
 
@@ -58,11 +46,14 @@ def group_indices(plan: PlacementPlan, indices: np.ndarray) -> np.ndarray:
 
 
 def _local_lookup(arena, bases, idx):
-    """arena: (R, D); bases: (K,); idx: (B, K, P) -> (B, K, D)."""
-    B, K, Pp = idx.shape
-    rebased = jnp.where(idx >= 0, idx + bases[None, :, None], 0)
-    out = embedding_bag_ref(arena, rebased.reshape(B * K, Pp))
-    return out.reshape(B, K, -1)
+    """arena: (R, D); bases: (K,); idx: (B, K, P) -> (B, K, D) f32.
+
+    Padded slots (-1) add nothing whatever arena row 0 holds; the mask
+    also keeps their gradient off row 0, so training leaves it zero."""
+    live = idx >= 0
+    rows = jnp.take(arena, jnp.where(live, idx + bases[None, :, None], 0),
+                    axis=0)                                # (B, K, P, D)
+    return jnp.where(live[..., None], rows, 0).astype(jnp.float32).sum(2)
 
 
 def make_sharded_lookup(mesh, plan: PlacementPlan, *,
@@ -96,7 +87,7 @@ def make_sharded_lookup(mesh, plan: PlacementPlan, *,
                                               plan.dim)
         return out
 
-    return shard_map(
+    return jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(P(model_axis, None, None), P(model_axis, None),
                   P(batch_spec, None, None)),

@@ -71,8 +71,9 @@ def _lower_dlrm(mesh, rules, batch=65536, n_tables=160, pool_slots=16):
     """Paper's own architecture: DLRM train step with table-parallel
     embedding (shard_map + all-to-all), DreamShard-style placement plan.
 
-    Arenas are stored at the native dim (16) -- the Pallas kernel pads to
-    128 lanes transiently; storing padded would waste 8x HBM.  Hash sizes
+    Arenas are stored at the native dim (16): padded to 128 lanes they
+    would take 8x the HBM.  The step looks rows up with XLA's gather
+    (``embedding/sharded.py``), not the Pallas kernel.  Hash sizes
     are clipped to 4e6 rows so the 160-table pool fits a v5e-16 shard
     budget (the paper's 11 GB GPUs hold ~20-80 tables per device)."""
     import numpy as np
@@ -82,8 +83,8 @@ def _lower_dlrm(mesh, rules, batch=65536, n_tables=160, pool_slots=16):
     from repro.data.synthetic import make_dlrm_pool
     from repro.embedding import sharded as E
     from repro.embedding.plan import build_plan
-    from repro.models.dlrm import DLRM, DLRMConfig
-    from repro.optim import adam, apply_updates, rowwise_adagrad
+    from repro.models.dlrm import DLRM, DLRMConfig, make_train_step
+    from repro.optim import adam, rowwise_adagrad
     from repro.optim.optimizers import OptState
     from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -103,20 +104,7 @@ def _lower_dlrm(mesh, rules, batch=65536, n_tables=160, pool_slots=16):
                                    model_axis=rules.model_axis)
     emb_opt = rowwise_adagrad(0.05)
     dense_opt = adam(1e-3)
-
-    def train_step(params, emb_state, dense_state, batch_in):
-        def loss_fn(p):
-            logits = model.forward(p, batch_in["dense"], batch_in["gidx"],
-                                   lookup)
-            return DLRM.loss(logits, batch_in["labels"])
-        loss, g = jax.value_and_grad(loss_fn)(params)
-        eu, emb_state = emb_opt.update({"arenas": g["arenas"]}, emb_state)
-        du, dense_state = dense_opt.update(
-            {k: g[k] for k in ("bottom", "top")}, dense_state)
-        params = {**apply_updates({k: params[k] for k in ("bottom", "top")},
-                                  du),
-                  **apply_updates({"arenas": params["arenas"]}, eu)}
-        return params, emb_state, dense_state, loss
+    train_step = make_train_step(model, lookup, emb_opt, dense_opt)
 
     aparams = jax.eval_shape(model.init_params, jax.random.PRNGKey(0))
     a_emb = jax.eval_shape(emb_opt.init, {"arenas": aparams["arenas"]})

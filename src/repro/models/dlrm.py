@@ -19,6 +19,9 @@ import numpy as np
 
 from repro.embedding import sharded as E
 from repro.embedding.plan import PlacementPlan
+from repro.optim import apply_updates
+
+DENSE_PARAMS = ("bottom", "top")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -101,3 +104,27 @@ class DLRM:
         logits = logits.astype(jnp.float32)
         return jnp.mean(jnp.maximum(logits, 0) - logits * labels
                         + jnp.log1p(jnp.exp(-jnp.abs(logits))))
+
+
+def make_train_step(model: DLRM, lookup_fn, emb_opt, dense_opt):
+    """One DLRM training step: ``emb_opt`` (row-wise) on the arenas and
+    ``dense_opt`` on the MLPs.
+
+    Returns ``step(params, emb_state, dense_state, batch) -> (params,
+    emb_state, dense_state, loss)``; ``batch`` holds ``"dense"`` (B, n_dense),
+    ``"gidx"`` (B, S*K, P) plan-grouped indices and ``"labels"`` (B,).
+    """
+    def step(params, emb_state, dense_state, batch):
+        def loss_fn(p):
+            logits = model.forward(p, batch["dense"], batch["gidx"],
+                                   lookup_fn)
+            return DLRM.loss(logits, batch["labels"])
+        loss, g = jax.value_and_grad(loss_fn)(params)
+        eu, emb_state = emb_opt.update({"arenas": g["arenas"]}, emb_state)
+        du, dense_state = dense_opt.update(
+            {k: g[k] for k in DENSE_PARAMS}, dense_state)
+        params = {**apply_updates({k: params[k] for k in DENSE_PARAMS}, du),
+                  **apply_updates({"arenas": params["arenas"]}, eu)}
+        return params, emb_state, dense_state, loss
+
+    return step

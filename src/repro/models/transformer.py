@@ -30,24 +30,6 @@ from repro.models.sharding import NO_SHARDING, ShardingRules
 from jax.sharding import PartitionSpec as P
 
 
-def _barrier_differentiable() -> bool:
-    """Older jax (< 0.5) has no differentiation rule for
-    ``optimization_barrier``; probe once and fall back to identity there
-    (the barrier is a memory-layout optimization, not a semantic one)."""
-    global _BARRIER_OK
-    if _BARRIER_OK is None:
-        try:
-            jax.grad(lambda v: jax.lax.optimization_barrier(v).sum())(
-                jnp.ones((1,)))
-            _BARRIER_OK = True
-        except NotImplementedError:
-            _BARRIER_OK = False
-    return _BARRIER_OK
-
-
-_BARRIER_OK: bool | None = None
-
-
 def _init(key, shape, scale, dtype):
     return (jax.random.normal(key, shape) * scale).astype(dtype)
 
@@ -377,8 +359,7 @@ class LM:
             # the barrier stops XLA from hoisting the rms_norm bf16->f32
             # convert of the whole saved activation stack out of the
             # backward loop (a 2x-per-elem temp blowup otherwise)
-            if _barrier_differentiable():
-                xc = jax.lax.optimization_barrier(xc)
+            xc = jax.lax.optimization_barrier(xc)
             xo, _, aux = self._layer(lp, xc, positions)
             return xo, aux
 

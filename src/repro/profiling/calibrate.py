@@ -119,6 +119,8 @@ def _up_to_date(path: str, grid: dict, fused_cfg: tuple | None,
 def main(argv=None) -> int:
     args = build_parser().parse_args(argv)
     from repro import telemetry as tele
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     with tele.trace_to(args.trace, quiet=args.quiet):
         return _main_impl(args)
 
@@ -132,6 +134,7 @@ def _main_impl(args) -> int:
                                              DEFAULT_SHARD_PER_FRAC,
                                              load_or_none)
     from repro.profiling.microbench import default_use_pallas
+    from repro.sim.hardware import PAPER_GPU, TPU_V5E
     grid = _resolve_grid(args)
     say = (lambda *a: None) if args.quiet else \
         (lambda *a: print(*a, flush=True))
@@ -139,6 +142,9 @@ def _main_impl(args) -> int:
     use_pallas = {"auto": None, "on": True, "off": False}[args.pallas]
     resolved_pallas = default_use_pallas() if use_pallas is None \
         else use_pallas
+    # on a one-device host this spec alone prices the synthetic all-to-all
+    import jax
+    spec = TPU_V5E if jax.default_backend() == "tpu" else PAPER_GPU
     if resolved_pallas:
         # mirror CalibrationTable.measure: the Pallas kernel pads dims to
         # 128 lanes, so the measured (and stored) dim axis is the padded,
@@ -198,7 +204,8 @@ def _main_impl(args) -> int:
     with tele.span("calibrate.sweep", shapes=n_shapes, repeats=repeats):
         table = CalibrationTable.measure(
             **grid, use_pallas=use_pallas, warmup=args.warmup,
-            repeats=repeats, seed=args.seed, fused=not args.no_fused,
+            repeats=repeats, seed=args.seed, spec=spec,
+            fused=not args.no_fused,
             fused_ks=fused_ks, fused_per_k=fused_per_k,
             sharded=not args.no_sharded, shard_fracs=shard_fracs,
             shard_per_frac=shard_per_frac,
@@ -206,6 +213,9 @@ def _main_impl(args) -> int:
             meta={"cli": True, "smoke": bool(args.smoke)})
     path = table.save(args.out)
     say(f"[calibrate] {table.summary()}")
+    say(f"[calibrate] all-to-all model: source={table.comm.source}"
+        + ("" if table.comm.source == "measured"
+           else f" (analytic {spec.name} constants, not a measurement)"))
     if not args.no_fused:
         say(f"[calibrate] fusion fwd {table.fusion_fwd.summary()}")
         say(f"[calibrate] fusion bwd {table.fusion_bwd.summary()}")

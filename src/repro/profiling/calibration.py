@@ -635,7 +635,7 @@ class CalibrationTable:
                                     repeats=repeats, seed=seed,
                                     progress=progress)
         if comm is None:
-            comm = calibrate_comm(spec=spec, warmup=warmup,
+            comm = calibrate_comm(spec, warmup=warmup,
                                   repeats=repeats, seed=seed)
         table = cls(dims=np.asarray(grid["dims"], np.float64),
                     rows=np.asarray(grid["rows"], np.float64),

@@ -93,7 +93,6 @@ def measure_all_to_all(payload_mb, *, devices=None, warmup: int = 1,
     import jax
     import jax.numpy as jnp
     from jax.sharding import Mesh, PartitionSpec as P
-    from repro.embedding.sharded import shard_map
     from repro.profiling.microbench import median_time_ms
 
     devices = list(jax.devices()) if devices is None else list(devices)
@@ -107,7 +106,7 @@ def measure_all_to_all(payload_mb, *, devices=None, warmup: int = 1,
         return jax.lax.all_to_all(x, "x", split_axis=0, concat_axis=0,
                                   tiled=True)
 
-    fn = jax.jit(shard_map(local, mesh=mesh, in_specs=P("x"),
+    fn = jax.jit(jax.shard_map(local, mesh=mesh, in_specs=P("x"),
                            out_specs=P("x"), check_vma=False))
     times = []
     for mb in payload_mb:
@@ -123,11 +122,15 @@ def measure_all_to_all(payload_mb, *, devices=None, warmup: int = 1,
     return np.asarray(times)
 
 
-def calibrate_comm(*, spec: HardwareSpec = PAPER_GPU, payload_mb=None,
+def calibrate_comm(spec: HardwareSpec, *, payload_mb=None,
                    devices=None, warmup: int = 1, repeats: int = 5,
                    seed: int = 0) -> CommModel:
     """Measure (multi-device) or synthesize (single-device) an all-to-all
-    trace and fit the alpha-beta model."""
+    trace and fit the alpha-beta model.
+
+    ``spec`` has no default: on a one-device host it alone prices the
+    synthetic trace, so every caller names the hardware it stands for
+    (and the result says ``source="synthetic"``)."""
     import jax
     payload_mb = DEFAULT_PAYLOAD_MB if payload_mb is None else payload_mb
     devices = list(jax.devices()) if devices is None else list(devices)

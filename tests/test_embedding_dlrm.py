@@ -108,17 +108,10 @@ idx = np.where(rng.random((B, M, P)) < 0.2, -1,
 gidx = jnp.asarray(E.group_indices(plan, idx))
 bases = jnp.asarray(plan.base_rows)
 ref = E.lookup_unsharded(arenas, plan.base_rows, gidx, plan)
-import contextlib
-try:                                        # jax >= 0.6
-    mesh = jax.make_mesh((2, 4), ("data", "model"),
-                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
-except (AttributeError, TypeError):         # older jax
-    mesh = jax.sharding.Mesh(np.array(jax.devices()).reshape(2, 4),
-                             ("data", "model"))
+mesh = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 lookup = E.make_sharded_lookup(mesh, plan)
-ctx = jax.set_mesh(mesh) if hasattr(jax, "set_mesh") \
-    else contextlib.nullcontext()
-with ctx:
+with jax.set_mesh(mesh):
     out = lookup(arenas, bases, gidx)
 assert np.allclose(np.asarray(out), np.asarray(ref), atol=1e-5), "mismatch"
 print("SHARDED_OK")
@@ -130,3 +123,23 @@ def test_sharded_lookup_matches_oracle_8dev():
     r = subprocess.run([sys.executable, "-c", _SHARDED_SCRIPT, src],
                        capture_output=True, text=True, timeout=600)
     assert "SHARDED_OK" in r.stdout, r.stdout + r.stderr
+
+
+def test_padded_slots_add_nothing_and_get_no_gradient(setup):
+    """Padded pooling slots (-1) point at arena row 0; the lookup must not
+    add that row even when it is non-zero, nor train it."""
+    model, params, plan, raw, idx, rng = setup
+    gidx = E.group_indices(plan, idx)
+    arenas = np.asarray(params["arenas"]).copy()
+    arenas[:, 0] = 1.0                           # a row 0 that is not zero
+    out = np.asarray(_oracle(plan)(jnp.asarray(arenas), None,
+                                   jnp.asarray(gidx)))
+    shard = np.repeat(np.arange(plan.n_shards), plan.k_max)
+    base = plan.base_rows.reshape(-1)
+    rows = arenas[shard[None, :, None], gidx + base[None, :, None]]
+    ref = np.where((gidx >= 0)[..., None], rows, 0.0).sum(axis=2)
+    np.testing.assert_allclose(out, ref, rtol=1e-5, atol=1e-6)
+
+    g = jax.grad(lambda a: _oracle(plan)(a, None, jnp.asarray(gidx)).sum())(
+        jnp.asarray(arenas))
+    np.testing.assert_array_equal(np.asarray(g)[:, 0], 0.0)
