@@ -164,20 +164,15 @@ class PlacementSession:
             args = (self.agent.policy_params, self.agent.cost_params,
                     jnp.asarray(feats), jnp.asarray(sizes),
                     jnp.asarray(tmask), self.agent.oracle.mem_capacity_gb)
+            # the span ends when the host holds the result; a fresh fn
+            # (``fresh_compile``) pays its jit trace and compile inside it
             with tele.span("session.decode", m_pad=m_pad,
                            n_devices=n_devices, tasks=B, b_pad=b_pad,
                            fresh_compile=fresh):
-                if fresh:
-                    # jit compiles lazily: a fresh fn pays its XLA trace
-                    # inside this first invocation
-                    with tele.span("session.compile", m_pad=m_pad,
-                                   n_devices=n_devices, b_pad=b_pad):
-                        actions, est = fn(*args)
-                else:
-                    actions, est = fn(*args)
+                actions, est = fn(*args)
+                actions, est = np.asarray(actions), np.asarray(est)
             self.num_decode_calls += 1
             tele.count("session.decode_calls")
-            actions, est = np.asarray(actions), np.asarray(est)
             for j, i in enumerate(idxs):
                 t, order = tasks[i], orders[j]
                 best = int(np.argmin(est[j]))

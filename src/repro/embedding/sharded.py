@@ -13,6 +13,11 @@ Inside the ``shard_map`` the lookup is XLA's gather plus a masked pooled
 sum, on every backend.  The Pallas kernel (``repro.kernels.embedding_bag``)
 is not on this path; which of the two the step should use is open until
 both are measured on the chip.
+
+The lookup runs under the named scope ``LOOKUP_SCOPE`` and the exchange
+under ``EXCHANGE_SCOPE``, so a device profile names their ops (and their
+transposes, the backward scatter-add and exchange).  Scopes are metadata
+only: the compiled program is the same without them.
 """
 
 from __future__ import annotations
@@ -24,6 +29,9 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from repro.embedding.plan import PlacementPlan
+
+LOOKUP_SCOPE = "emb.lookup"
+EXCHANGE_SCOPE = "emb.exchange"
 
 
 def init_arenas(key, plan: PlacementPlan, dtype=jnp.float32,
@@ -50,10 +58,13 @@ def _local_lookup(arena, bases, idx):
 
     Padded slots (-1) add nothing whatever arena row 0 holds; the mask
     also keeps their gradient off row 0, so training leaves it zero."""
-    live = idx >= 0
-    rows = jnp.take(arena, jnp.where(live, idx + bases[None, :, None], 0),
-                    axis=0)                                # (B, K, P, D)
-    return jnp.where(live[..., None], rows, 0).astype(jnp.float32).sum(2)
+    with jax.named_scope(LOOKUP_SCOPE):
+        live = idx >= 0
+        rows = jnp.take(arena,
+                        jnp.where(live, idx + bases[None, :, None], 0),
+                        axis=0)                            # (B, K, P, D)
+        return jnp.where(live[..., None], rows, 0).astype(
+            jnp.float32).sum(2)
 
 
 def make_sharded_lookup(mesh, plan: PlacementPlan, *,
@@ -78,14 +89,14 @@ def make_sharded_lookup(mesh, plan: PlacementPlan, *,
         m = jax.lax.axis_index(model_axis)
         own = jax.lax.dynamic_index_in_dim(idx, m, axis=1, keepdims=False)
         out = _local_lookup(arena, bases[0], own)      # (B_loc, K, D)
-        # forward all-to-all: trade batch rows for table groups
-        out = jax.lax.all_to_all(
-            out.reshape(S, out.shape[0] // S, plan.k_max, plan.dim),
-            model_axis, split_axis=0, concat_axis=0, tiled=False)
-        # (S, B_loc/S, K, D) -> (B_loc/S, S*K, D)
-        out = jnp.moveaxis(out, 0, 1).reshape(out.shape[1], S * plan.k_max,
-                                              plan.dim)
-        return out
+        with jax.named_scope(EXCHANGE_SCOPE):
+            # forward all-to-all: trade batch rows for table groups
+            out = jax.lax.all_to_all(
+                out.reshape(S, out.shape[0] // S, plan.k_max, plan.dim),
+                model_axis, split_axis=0, concat_axis=0, tiled=False)
+            # (S, B_loc/S, K, D) -> (B_loc/S, S*K, D)
+            return jnp.moveaxis(out, 0, 1).reshape(
+                out.shape[1], S * plan.k_max, plan.dim)
 
     return jax.shard_map(
         local_fn, mesh=mesh,

@@ -7,6 +7,11 @@ interaction with the dense representation -> top MLP -> CTR logit.
 The dense parts are data-parallel (replicated params, batch-sharded
 activations); the embedding arenas are model-parallel via
 ``repro.embedding.sharded``.
+
+Each layer of the step runs under a named scope (``SCOPES``), so a
+device profile of the step names its ops by layer: the forward op under
+``jvp(<scope>)``, its backward under ``transpose(jvp(<scope>))``.  The
+scopes are metadata only; the compiled step is the same without them.
 """
 
 from __future__ import annotations
@@ -22,6 +27,16 @@ from repro.embedding.plan import PlacementPlan
 from repro.optim import apply_updates
 
 DENSE_PARAMS = ("bottom", "top")
+
+BOTTOM_SCOPE = "dlrm.bottom"              # bottom MLP
+EMBED_SCOPE = "dlrm.embed"                # the lookup and the slot reorder
+INTERACT_SCOPE = "dlrm.interact"          # pairwise dot interaction
+TOP_SCOPE = "dlrm.top"                    # top MLP
+LOSS_SCOPE = "dlrm.loss"                  # BCE
+EMB_UPDATE_SCOPE = "dlrm.emb_update"      # row-wise update + arena apply
+DENSE_UPDATE_SCOPE = "dlrm.dense_update"  # dense optimizer + apply
+SCOPES = (BOTTOM_SCOPE, EMBED_SCOPE, INTERACT_SCOPE, TOP_SCOPE, LOSS_SCOPE,
+          EMB_UPDATE_SCOPE, DENSE_UPDATE_SCOPE)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -87,23 +102,28 @@ class DLRM:
         Returns CTR logits (B,).
         """
         plan = self.plan
-        bases = jnp.asarray(plan.base_rows)
-        sparse_all = lookup_fn(params["arenas"], bases, grouped_indices)
-        # drop padded slots, keep true tables in original order
-        order = plan.grouped_index_order()
-        keep = np.flatnonzero(order >= 0)
-        inv = keep[np.argsort(order[keep], kind="stable")]
-        sparse = jnp.take(sparse_all, jnp.asarray(inv), axis=1)
-        dense_rep = _mlp(params["bottom"], dense.astype(self.dtype))
-        x = self._interact(dense_rep, sparse.astype(self.dtype))
-        return _mlp(params["top"], x)[:, 0]
+        with jax.named_scope(EMBED_SCOPE):
+            bases = jnp.asarray(plan.base_rows)
+            sparse_all = lookup_fn(params["arenas"], bases, grouped_indices)
+            # drop padded slots, keep true tables in original order
+            order = plan.grouped_index_order()
+            keep = np.flatnonzero(order >= 0)
+            inv = keep[np.argsort(order[keep], kind="stable")]
+            sparse = jnp.take(sparse_all, jnp.asarray(inv), axis=1)
+        with jax.named_scope(BOTTOM_SCOPE):
+            dense_rep = _mlp(params["bottom"], dense.astype(self.dtype))
+        with jax.named_scope(INTERACT_SCOPE):
+            x = self._interact(dense_rep, sparse.astype(self.dtype))
+        with jax.named_scope(TOP_SCOPE):
+            return _mlp(params["top"], x)[:, 0]
 
     @staticmethod
     def loss(logits, labels):
         """Binary cross-entropy with logits."""
-        logits = logits.astype(jnp.float32)
-        return jnp.mean(jnp.maximum(logits, 0) - logits * labels
-                        + jnp.log1p(jnp.exp(-jnp.abs(logits))))
+        with jax.named_scope(LOSS_SCOPE):
+            logits = logits.astype(jnp.float32)
+            return jnp.mean(jnp.maximum(logits, 0) - logits * labels
+                            + jnp.log1p(jnp.exp(-jnp.abs(logits))))
 
 
 def make_train_step(model: DLRM, lookup_fn, emb_opt, dense_opt):
@@ -120,11 +140,14 @@ def make_train_step(model: DLRM, lookup_fn, emb_opt, dense_opt):
                                    lookup_fn)
             return DLRM.loss(logits, batch["labels"])
         loss, g = jax.value_and_grad(loss_fn)(params)
-        eu, emb_state = emb_opt.update({"arenas": g["arenas"]}, emb_state)
-        du, dense_state = dense_opt.update(
-            {k: g[k] for k in DENSE_PARAMS}, dense_state)
-        params = {**apply_updates({k: params[k] for k in DENSE_PARAMS}, du),
-                  **apply_updates({"arenas": params["arenas"]}, eu)}
-        return params, emb_state, dense_state, loss
+        with jax.named_scope(EMB_UPDATE_SCOPE):
+            eu, emb_state = emb_opt.update({"arenas": g["arenas"]},
+                                           emb_state)
+            arenas = apply_updates({"arenas": params["arenas"]}, eu)
+        with jax.named_scope(DENSE_UPDATE_SCOPE):
+            du, dense_state = dense_opt.update(
+                {k: g[k] for k in DENSE_PARAMS}, dense_state)
+            dense = apply_updates({k: params[k] for k in DENSE_PARAMS}, du)
+        return {**dense, **arenas}, emb_state, dense_state, loss
 
     return step

@@ -25,10 +25,17 @@ Usage::
 
 ``sinks.py`` holds the exporters (Chrome ``trace_event`` JSON, JSONL,
 plain-text summary); ``report.py`` is the CLI over a persisted trace.
+
+While enabled, each span also opens a ``jax.profiler.TraceAnnotation`` of
+its name over the same interval, so a ``jax.profiler`` trace shows the
+span on its host plane, on the clock of the device ops.  jax is imported
+on the first ``enable()``, not before; where it does not import, spans
+are recorded all the same.
 """
 
 from __future__ import annotations
 
+import functools
 import itertools
 import threading
 import time
@@ -82,7 +89,7 @@ class Span:
     only exist once the round ran.
     """
 
-    __slots__ = ("_tracer", "name", "args", "id", "parent", "_t0")
+    __slots__ = ("_tracer", "name", "args", "id", "parent", "_t0", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, args: dict):
         self._tracer = tracer
@@ -91,6 +98,7 @@ class Span:
         self.id = next(tracer._ids)
         self.parent = None
         self._t0 = 0.0
+        self._ann = None
 
     def set(self, **attrs) -> "Span":
         self.args.update(attrs)
@@ -100,11 +108,16 @@ class Span:
         stack = self._tracer._stack()
         self.parent = stack[-1].id if stack else None
         stack.append(self)
+        if self._tracer.annotation is not None:
+            self._ann = self._tracer.annotation(self.name)
+            self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc) -> bool:
         t1 = time.perf_counter()
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
         stack = self._tracer._stack()
         if stack and stack[-1] is self:
             stack.pop()
@@ -137,11 +150,15 @@ class Tracer:
     parent id) and finished spans are appended to one bounded in-memory
     event list as ``(name, ts_us, dur_us, tid, span_id, parent_id,
     args)`` tuples -- microseconds since the tracer's epoch, the unit
-    Chrome's ``trace_event`` format wants natively.
+    Chrome's ``trace_event`` format wants natively.  ``annotation``, a
+    context manager type taking the span's name, is opened around each
+    span (``enable()`` passes ``jax.profiler.TraceAnnotation``).
     """
 
-    def __init__(self, max_events: int = DEFAULT_MAX_EVENTS):
+    def __init__(self, max_events: int = DEFAULT_MAX_EVENTS,
+                 annotation=None):
         self.max_events = max_events
+        self.annotation = annotation
         self.epoch = time.perf_counter()
         self.epoch_unix = time.time()
         self.events: list[tuple] = []
@@ -214,11 +231,23 @@ _REGISTRY = MetricsRegistry()
 _TRACER: Tracer | None = None
 
 
+@functools.cache
+def _profiler_annotation():
+    """``jax.profiler.TraceAnnotation``, or None where jax does not
+    import."""
+    try:
+        from jax.profiler import TraceAnnotation
+    except ImportError:
+        return None
+    return TraceAnnotation
+
+
 def enable(max_events: int = DEFAULT_MAX_EVENTS) -> Tracer:
     """Install the process tracer (idempotent); returns it."""
     global _TRACER
     if _TRACER is None:
-        _TRACER = Tracer(max_events=max_events)
+        _TRACER = Tracer(max_events=max_events,
+                         annotation=_profiler_annotation())
     return _TRACER
 
 

@@ -260,6 +260,47 @@ def test_session_bucket_reuse_across_interleaved_batches(
     assert tele.counter_value("session.bucket_compiles") == 3
 
 
+def test_session_decode_span_lasts_until_the_host_holds_the_result(
+        suite, telemetry):
+    """``session.decode`` ends when the decode's result is on the host:
+    a result that becomes ready only after a delay gives a span at least
+    that long."""
+    import time
+    delay = 0.25
+    tasks, _, agent = suite
+    session = PlacementSession(agent, bucket_tables=8)
+    session.place(tasks[0])                        # compile outside
+
+    class Late:
+        """An array the host can read only ``delay`` seconds on."""
+
+        def __init__(self, value):
+            self.value = value
+
+        def __array__(self, dtype=None, copy=None):
+            time.sleep(delay)
+            return np.asarray(self.value, dtype=dtype)
+
+    decode_fn = session._decode_fn
+
+    def late_fn(*key):
+        fn = decode_fn(*key)
+        return lambda *args: tuple(map(Late, fn(*args)))
+
+    session._decode_fn = late_fn
+    telemetry.reset()
+    placed = session.place(tasks[0])
+    np.testing.assert_array_equal(
+        placed.assignment, agent.place(tasks[0].raw_features,
+                                       tasks[0].n_devices))
+    (decode,) = [e for e in telemetry.get_tracer().snapshot_events()
+                 if e[0] == "session.decode"]
+    assert decode[2] >= 2 * delay * 1e6            # two reads, dur in us
+    assert decode[6]["fresh_compile"] is False
+    assert not [e for e in telemetry.get_tracer().snapshot_events()
+                if e[0] == "session.compile"]
+
+
 def test_session_estimates_match_per_task(suite):
     tasks, _, agent = suite
     session = PlacementSession(agent)

@@ -8,6 +8,9 @@ counter increments under thread contention, and sink round-trips
 """
 
 import json
+import os
+import subprocess
+import sys
 import threading
 
 import numpy as np
@@ -236,3 +239,72 @@ def test_write_without_tracer_raises(tmp_path):
     assert not tele.is_enabled()
     with pytest.raises(RuntimeError, match="not enabled"):
         tele.write_chrome_trace(str(tmp_path / "x.json"))
+
+
+# ---- the profiler's clock ---------------------------------------------------
+
+
+def test_enabled_spans_reach_the_profiler_host_plane(telemetry, tmp_path):
+    """Each enabled span opens a ``jax.profiler.TraceAnnotation`` of its
+    name: a profiler trace shows it on a host plane, beside its args'
+    recording in the tracer."""
+    import glob
+    import jax
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with telemetry.span("probe.host_plane", rows=3):
+            jax.numpy.ones(4).block_until_ready()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                        recursive=True)
+    planes = {plane.name for plane in
+              jax.profiler.ProfileData.from_file(path).planes
+              for line in plane.lines for ev in line.events
+              if ev.name == "probe.host_plane"}
+    assert planes and all(p.startswith("/host:") for p in planes)
+    names = [e[0] for e in telemetry.get_tracer().snapshot_events()]
+    assert names == ["probe.host_plane"]
+
+
+def test_annotation_closes_with_its_span_and_disabled_path_stays_noop():
+    log = []
+
+    class Annotation:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            log.append(("enter", self.name))
+
+        def __exit__(self, *exc):
+            log.append(("exit", self.name))
+
+    tracer = core.Tracer(annotation=Annotation)
+    with pytest.raises(ValueError):
+        with tracer.span("outer", {}):
+            with tracer.span("inner", {}):
+                raise ValueError
+    assert log == [("enter", "outer"), ("enter", "inner"),
+                   ("exit", "inner"), ("exit", "outer")]
+    assert not tele.is_enabled()
+    assert tele.span("x") is tele.NOOP_SPAN
+
+
+def test_telemetry_imports_and_records_without_jax():
+    """jax is imported on the first ``enable()``, not on import; where it
+    does not import, spans record all the same."""
+    code = ("import sys; sys.modules['jax'] = None\n"
+            "from repro import telemetry as tele\n"
+            "assert tele.span('x') is tele.NOOP_SPAN\n"
+            "tracer = tele.enable()\n"
+            "with tele.span('y'):\n"
+            "    pass\n"
+            "assert tracer.annotation is None\n"
+            "assert [e[0] for e in tracer.snapshot_events()] == ['y']\n"
+            "print('OK')\n")
+    src = os.path.join(os.path.dirname(__file__), "..", "src")
+    r = subprocess.run([sys.executable, "-c", code],
+                       env={**os.environ, "PYTHONPATH": src},
+                       capture_output=True, text=True, timeout=120)
+    assert r.stdout.strip() == "OK", r.stderr
