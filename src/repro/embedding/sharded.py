@@ -10,18 +10,29 @@ the forward all-to-all of the paper; the transpose in the backward pass is
 the backward all-to-all.
 
 Inside the ``shard_map`` the lookup is XLA's gather plus a masked pooled
-sum, on every backend.  The Pallas kernel (``repro.kernels.embedding_bag``)
-is not on this path; which of the two the step should use is open until
-both are measured on the chip.
+sum, on every backend; the Pallas forward kernel
+(``repro.kernels.embedding_bag.kernel``) is not on this path.
+
+Its backward is a custom VJP that keeps only the indices.  It sorts the
+shard's slots by arena row, with padded slots sent past the last row
+(``BWD_SORT_SCOPE``); puts each slot's pooled gradient into that order in
+f32 (``BWD_FETCH_SCOPE``); and adds each row's gradients in f32 and
+writes the dense (R, D) arena gradient in one pass, rounded once to the
+arena's dtype (``BWD_ACCUMULATE_SCOPE``).  On a TPU the sums are the
+Pallas kernel ``repro.kernels.embedding_bag.backward``; elsewhere its
+plain-JAX form, a sorted ``segment_sum``.  So padding costs no update and
+a hot row's sum does not stall at bf16's precision.
 
 The lookup runs under the named scope ``LOOKUP_SCOPE`` and the exchange
 under ``EXCHANGE_SCOPE``, so a device profile names their ops (and their
-transposes, the backward scatter-add and exchange).  Scopes are metadata
-only: the compiled program is the same without them.
+transposes: the backward's three steps, under ``transpose(jvp(...))``, and
+the backward exchange).  Scopes are metadata only: the compiled program is
+the same without them.
 """
 
 from __future__ import annotations
 
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -29,9 +40,14 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from repro.embedding.plan import PlacementPlan
+from repro.kernels.embedding_bag import backward as bwd_kernel
 
 LOOKUP_SCOPE = "emb.lookup"
 EXCHANGE_SCOPE = "emb.exchange"
+BWD_SORT_SCOPE = "emb.bwd.sort"
+BWD_FETCH_SCOPE = "emb.bwd.fetch"
+BWD_ACCUMULATE_SCOPE = "emb.bwd.accumulate"
+BWD_SCOPES = (BWD_SORT_SCOPE, BWD_FETCH_SCOPE, BWD_ACCUMULATE_SCOPE)
 
 
 def init_arenas(key, plan: PlacementPlan, dtype=jnp.float32,
@@ -56,15 +72,56 @@ def group_indices(plan: PlacementPlan, indices: np.ndarray) -> np.ndarray:
 def _local_lookup(arena, bases, idx):
     """arena: (R, D); bases: (K,); idx: (B, K, P) -> (B, K, D) f32.
 
-    Padded slots (-1) add nothing whatever arena row 0 holds; the mask
-    also keeps their gradient off row 0, so training leaves it zero."""
+    Padded slots (-1) add nothing whatever arena row 0 holds, and get no
+    gradient, so training leaves row 0 zero."""
     with jax.named_scope(LOOKUP_SCOPE):
-        live = idx >= 0
-        rows = jnp.take(arena,
-                        jnp.where(live, idx + bases[None, :, None], 0),
-                        axis=0)                            # (B, K, P, D)
-        return jnp.where(live[..., None], rows, 0).astype(
-            jnp.float32).sum(2)
+        return _lookup(arena.shape, arena.dtype, arena, bases, idx)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _lookup(shape, dtype, arena, bases, idx):
+    live = idx >= 0
+    rows = jnp.take(arena, jnp.where(live, idx + bases[None, :, None], 0),
+                    axis=0)                                # (B, K, P, D)
+    return jnp.where(live[..., None], rows, 0).astype(jnp.float32).sum(2)
+
+
+def _lookup_fwd(shape, dtype, arena, bases, idx):
+    return _lookup(shape, dtype, arena, bases, idx), (bases, idx)
+
+
+def _lookup_bwd(shape, dtype, res, g):
+    """Sorted f32 row sums of the pooled gradients ``g`` (B, K, D)."""
+    bases, idx = res
+    n_rows, dim = shape
+    bag_slots = idx.shape[-1]
+    with jax.named_scope(BWD_SORT_SCOPE):
+        keys = jnp.where(idx >= 0, idx + bases[None, :, None],
+                         n_rows).reshape(-1).astype(jnp.int32)
+        n = keys.shape[0]
+        bags = jnp.arange(n, dtype=jnp.int32) // bag_slots
+        pad = -n % bwd_kernel.CHUNK
+        if pad:
+            keys = jnp.concatenate([keys, jnp.full((pad,), n_rows, jnp.int32)])
+            bags = jnp.concatenate([bags, jnp.zeros((pad,), jnp.int32)])
+        keys, bags = jax.lax.sort((keys, bags), num_keys=1, is_stable=False)
+    with jax.named_scope(BWD_FETCH_SCOPE):
+        # indexed by (sample, slot) in ``g`` as it arrives: from a flat
+        # (B * K, D) copy, which it keeps in VMEM, the TPU compiler takes
+        # about two minutes over the same gather
+        k = g.shape[1]
+        grads = g.astype(jnp.float32)[bags // k, bags % k]      # (N, D)
+    with jax.named_scope(BWD_ACCUMULATE_SCOPE):
+        sums = functools.partial(bwd_kernel.sorted_row_sum, n_rows=n_rows,
+                                 dtype=dtype)
+        plain = functools.partial(bwd_kernel.sorted_row_sum_ref,
+                                  n_rows=n_rows, dtype=dtype)
+        d_arena = jax.lax.platform_dependent(keys, grads, tpu=sums,
+                                             default=plain)
+    return d_arena, None, None
+
+
+_lookup.defvjp(_lookup_fwd, _lookup_bwd)
 
 
 def make_sharded_lookup(mesh, plan: PlacementPlan, *,

@@ -6,7 +6,10 @@ into a zero-row arena (done once, at placement time, by
 indices, and dispatches the Pallas kernel (interpret mode on CPU, compiled
 on TPU).  Backward is the row-wise scatter-add from ``ref.py`` (the
 backward FBGEMM kernel would mirror the forward's scalar-prefetch pattern;
-on the paper's cost model it is bwd_comp = bwd_scale x fwd traffic).
+on the paper's cost model it is bwd_comp = bwd_scale x fwd traffic); this
+is the op calibration prices.  The placed step's lookup
+(``repro.embedding.sharded``) is not this op: its backward sorts the slots
+by arena row and sums them with ``backward.py``'s kernel.
 """
 
 from __future__ import annotations

@@ -20,7 +20,8 @@ from repro.embedding.plan import build_plan
 from repro.models import dlrm
 from repro.optim import adam, rowwise_adagrad
 
-PROGRAM_SCOPES = (*dlrm.SCOPES, E.LOOKUP_SCOPE, E.EXCHANGE_SCOPE)
+PROGRAM_SCOPES = (*dlrm.SCOPES, E.LOOKUP_SCOPE, E.EXCHANGE_SCOPE,
+                  *E.BWD_SCOPES)
 _OP_NAME = re.compile(r'^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=.*?'
                       r'metadata=\{[^}]*?op_name="([^"]*)"')
 _WRAPPED = re.compile(r"[\w.\-]+\((.*)\)")
@@ -102,15 +103,22 @@ def scoped():
     return tiny_step_hlo()
 
 
-@pytest.mark.parametrize("scope", dlrm.SCOPES + (E.LOOKUP_SCOPE,))
+@pytest.mark.parametrize("scope",
+                         dlrm.SCOPES + (E.LOOKUP_SCOPE,) + E.BWD_SCOPES)
 def test_compiled_step_carries_every_scope(scoped, scope):
     assert any(scope in scopes_of(n) for n in op_names(scoped).values())
 
 
 def test_lookup_backward_is_named_by_its_transpose(scoped):
-    names = op_names(scoped).values()
-    assert any("transpose(" in n and E.LOOKUP_SCOPE in scopes_of(n)
-               and "scatter-add" in n for n in names)
+    """The backward's sort, fetch and accumulate ops sit under the
+    lookup's transpose, where a trace reduction files them as embedding
+    backward (``transpose(`` and the lookup's scope in ``op_name``)."""
+    names = list(op_names(scoped).values())
+    for scope in E.BWD_SCOPES:
+        under = [n for n in names if scope in scopes_of(n)]
+        assert under, scope
+        for n in under:
+            assert "transpose(" in n and E.LOOKUP_SCOPE in scopes_of(n), n
 
 
 def test_scopes_leave_the_compiled_step_unchanged(scoped):

@@ -10,12 +10,14 @@ running this file loads the TPU library.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.embedding import sharded as E
 from repro.kernels.embedding_bag.kernel import embedding_bag_fused
 
 
@@ -55,3 +57,34 @@ def test_embedding_bag_compiles_for_v5e(one_chip, rows, n_bags, dtype):
     arena_bytes = rows * 128 * jnp.dtype(dtype).itemsize
     copied = compiled.memory_analysis().temp_size_in_bytes >= arena_bytes
     assert copied == (dtype == jnp.bfloat16 and rows % 2 == 1)
+
+
+@pytest.mark.parametrize("rows,batch,dim,dtype", [
+    (20_512_829, 65536, 16, jnp.bfloat16),    # largest DLRM-50 shard
+    (4_000_000, 4096, 128, jnp.float32),      # rows-major orientation
+])
+def test_lookup_backward_compiles_for_v5e(one_chip, rows, batch, dim, dtype):
+    """The lookup's backward (13 slots of 16 per sample): the sorted row
+    sums are the Pallas kernel, no scatter is left under the lookup's
+    transpose, and the (R, D) gradient is written in place, never
+    copied."""
+    k, pool = 13, 16
+    arena = jax.ShapeDtypeStruct((rows, dim), dtype, sharding=one_chip)
+    bases = jax.ShapeDtypeStruct((k,), jnp.int32, sharding=one_chip)
+    idx = jax.ShapeDtypeStruct((batch, k, pool), jnp.int32, sharding=one_chip)
+    g = jax.ShapeDtypeStruct((batch, k, dim), jnp.float32, sharding=one_chip)
+
+    def backward(a, b, i, g):
+        return jax.vjp(lambda a: E._local_lookup(a, b, i), a)[1](g)[0]
+
+    compiled = jax.jit(backward).lower(arena, bases, idx, g).compile()
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" in hlo
+    scatters = [line for line in hlo.splitlines()
+                if re.search(r"\bscatter\(", line) and E.LOOKUP_SCOPE in line]
+    assert not scatters, scatters[:2]
+    # temporaries: the fetched f32 rows and four int32 slot arrays; a copy
+    # of the (R, D) gradient would add rows * dim * itemsize more
+    slots = batch * k * pool
+    budget = slots * dim * 4 + 4 * slots * 4
+    assert compiled.memory_analysis().temp_size_in_bytes <= budget
