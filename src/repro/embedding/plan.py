@@ -24,6 +24,7 @@ import dataclasses
 
 import numpy as np
 
+from repro import telemetry
 from repro.core import features as F
 
 
@@ -41,6 +42,19 @@ class PlacementPlan:
     table_rows: np.ndarray        # (M,) rows per table
     sharding: object | None = None   # ShardSpec behind a column-sharded plan
     slot_cols: np.ndarray | None = None  # (n_shards, k_max, 2) [start, end)
+    bag_widths: np.ndarray | None = None  # (n_shards, k_max) ids a sample
+    col_slot: np.ndarray | None = None    # (n_shards, n_cols) slot or -1
+
+    @property
+    def n_cols(self) -> int:
+        """W: index columns per shard of a plan with bag widths."""
+        return int(self.col_slot.shape[1])
+
+    def col_ranges(self, s: int) -> list[tuple[int, int]]:
+        """[start, end) index columns of each of shard ``s``'s slots."""
+        ends = np.cumsum(self.bag_widths[s])
+        return [(int(e - w), int(e)) for e, w in zip(ends,
+                                                     self.bag_widths[s])]
 
     @property
     def n_tables(self) -> int:
@@ -61,7 +75,12 @@ class PlacementPlan:
 
 def build_plan(raw_features: np.ndarray, assignment: np.ndarray,
                n_shards: int, pad_dim_to: int = 128,
-               sharding=None) -> PlacementPlan:
+               sharding=None, widths=None,
+               pad_rows_to: int = 1) -> PlacementPlan:
+    """``widths``: (M,) ids per sample of each table (its fixed multi-hot
+    size), for the column layout; ``pad_rows_to``: round ``rows_max`` up
+    to a multiple of this (the row update writes bf16 rows in pairs, and
+    at a multiple of 16 its pair view of the arena is a bitcast)."""
     assignment = np.asarray(assignment)
     rows = raw_features[:, F.HASH_SIZE].astype(np.int64)
     dim = int(raw_features[:, F.DIM].max())
@@ -76,6 +95,7 @@ def build_plan(raw_features: np.ndarray, assignment: np.ndarray,
     k_max = max(1, max(len(g) for g in groups))
     rows_max = 1 + max(int(rows[owner[g]].sum()) if len(g) else 0
                        for g in groups)
+    rows_max = -(-rows_max // pad_rows_to) * pad_rows_to
 
     base = np.zeros((n_shards, k_max), np.int64)
     slot = np.full((n_shards, k_max), -1, np.int64)
@@ -90,7 +110,23 @@ def build_plan(raw_features: np.ndarray, assignment: np.ndarray,
             if cols is not None:
                 cols[s, j] = (sharding.col_start[i], sharding.col_end[i])
             r += int(rows[owner[i]])
+    bag_widths = col_slot = None
+    if widths is not None:
+        widths = np.asarray(widths, np.int64)
+        if sharding is not None or widths.shape != rows.shape \
+                or (widths < 1).any():
+            raise ValueError("bag widths need an unsharded plan and one "
+                             "positive width per table")
+        bag_widths = np.where(slot >= 0, widths[np.maximum(slot, 0)], 0)
+        n_cols = int(bag_widths.sum(axis=1).max())
+        col_slot = np.full((n_shards, n_cols), -1, np.int64)
+        for s in range(n_shards):
+            col_slot[s, :bag_widths[s].sum()] = np.repeat(
+                np.arange(k_max), bag_widths[s])
+        telemetry.count("plan.bag_columns", n_shards * n_cols)
+        telemetry.count("plan.live_columns", int(bag_widths.sum()))
     return PlacementPlan(assignment=assignment, n_shards=n_shards, dim=dimp,
                          k_max=k_max, rows_max=rows_max, groups=groups,
                          base_rows=base, slot_table=slot, table_rows=rows,
-                         sharding=sharding, slot_cols=cols)
+                         sharding=sharding, slot_cols=cols,
+                         bag_widths=bag_widths, col_slot=col_slot)

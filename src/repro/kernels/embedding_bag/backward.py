@@ -70,12 +70,13 @@ def _split3(g):
 
 
 def _row_sum_kernel(bounds_ref, keys_hbm, grads_hbm, out_ref,
-                    kbuf, gbuf, acc, sem, *, n_rows: int, by_lanes: bool):
+                    kbuf, gbuf, acc, sem, *, n_rows: int, by_lanes: bool,
+                    block_rows: int):
     i = pl.program_id(0)
     lo, hi = bounds_ref[i], bounds_ref[i + 1]
     first = lo // CHUNK
     n_chunks = jnp.where(hi > lo, (hi - 1) // CHUNK - first + 1, 0)
-    start = i * BLOCK_ROWS
+    start = i * block_rows
     key_rows = CHUNK // _LANES
 
     def copies(c, slot):
@@ -94,7 +95,7 @@ def _row_sum_kernel(bounds_ref, keys_hbm, grads_hbm, out_ref,
             cp.start()
 
     acc[...] = jnp.zeros(acc.shape, acc.dtype)
-    rows_iota = jax.lax.broadcasted_iota(jnp.int32, (BLOCK_ROWS, _LANES),
+    rows_iota = jax.lax.broadcasted_iota(jnp.int32, (block_rows, _LANES),
                                          0)
     sub_iota = jax.lax.broadcasted_iota(jnp.int32, (key_rows, _LANES), 0)
 
@@ -145,36 +146,41 @@ def _row_sum_kernel(bounds_ref, keys_hbm, grads_hbm, out_ref,
     out_ref[...] = acc[...].astype(out_ref.dtype)
 
 
-def _block_bounds(keys: jax.Array, n_rows: int) -> jax.Array:
-    """(n_blocks + 1,) int32: where each block of ``BLOCK_ROWS`` arena rows
+def _block_bounds(keys: jax.Array, n_rows: int,
+                  block_rows: int = BLOCK_ROWS) -> jax.Array:
+    """(n_blocks + 1,) int32: where each block of ``block_rows`` arena rows
     starts in the sorted ``keys``; the last entry ends the live slots."""
-    starts = jnp.minimum(jnp.arange(pl.cdiv(n_rows, BLOCK_ROWS) + 1,
-                                    dtype=jnp.int32) * BLOCK_ROWS, n_rows)
+    starts = jnp.minimum(jnp.arange(pl.cdiv(n_rows, block_rows) + 1,
+                                    dtype=jnp.int32) * block_rows, n_rows)
     return jnp.searchsorted(keys, starts, side="left",
                             method="scan").astype(jnp.int32)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("n_rows", "dtype", "interpret"))
+@functools.partial(jax.jit, static_argnames=("n_rows", "dtype", "interpret",
+                                             "block_rows"))
 def sorted_row_sum(keys: jax.Array, grads: jax.Array, *, n_rows: int,
-                   dtype, interpret: bool = False) -> jax.Array:
+                   dtype, interpret: bool = False,
+                   block_rows: int = BLOCK_ROWS) -> jax.Array:
     """keys: (N,) int32 ascending; grads: (N, D) f32 in the same order ->
     (n_rows, D) ``dtype``, row r the sum of the grads whose key is r.
-    N is a multiple of ``CHUNK``; keys >= ``n_rows`` add nothing."""
+    N is a multiple of ``CHUNK``; keys >= ``n_rows`` add nothing.
+    ``block_rows`` (a multiple of 128): rows per grid step; fewer suit
+    keys that are dense in ``[0, n_rows)``, as compact row ids are."""
     n, dim = grads.shape
     assert n % CHUNK == 0, f"pad the slots to a multiple of {CHUNK}"
     by_lanes = _lanes_major(dim)
-    n_blocks = pl.cdiv(n_rows, BLOCK_ROWS)
+    n_blocks = pl.cdiv(n_rows, block_rows)
     if by_lanes:
         g_in, g_buf = grads.T, (2, dim, CHUNK)
-        out_shape, out_block = (dim, n_rows), (dim, BLOCK_ROWS)
+        out_shape, out_block = (dim, n_rows), (dim, block_rows)
         out_map = lambda i, bounds: (0, i)                       # noqa: E731
     else:
         g_in, g_buf = grads, (2, CHUNK, dim)
-        out_shape, out_block = (n_rows, dim), (BLOCK_ROWS, dim)
+        out_shape, out_block = (n_rows, dim), (block_rows, dim)
         out_map = lambda i, bounds: (i, 0)                       # noqa: E731
     out = pl.pallas_call(
-        functools.partial(_row_sum_kernel, n_rows=n_rows, by_lanes=by_lanes),
+        functools.partial(_row_sum_kernel, n_rows=n_rows, by_lanes=by_lanes,
+                          block_rows=block_rows),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(n_blocks,),
@@ -190,7 +196,8 @@ def sorted_row_sum(keys: jax.Array, grads: jax.Array, *, n_rows: int,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(_block_bounds(keys, n_rows), keys.reshape(-1, _LANES), g_in)
+    )(_block_bounds(keys, n_rows, block_rows), keys.reshape(-1, _LANES),
+      g_in)
     return out.T if by_lanes else out
 
 

@@ -2,5 +2,5 @@
 
 from repro.optim.optimizers import (  # noqa: F401
     adam, adamw, sgd, rowwise_adagrad, apply_updates, linear_decay,
-    OptState, Optimizer,
+    OptState, Optimizer, RowWiseAdagrad,
 )

@@ -130,5 +130,22 @@ def rowwise_adagrad(lr: Schedule, eps: float = 1e-8) -> Optimizer:
     return Optimizer(init, update)
 
 
+@dataclasses.dataclass(frozen=True)
+class RowWiseAdagrad:
+    """Row-wise Adagrad that touches only the rows a step looked up.
+
+    Not an ``Optimizer``: ``repro.models.dlrm.make_train_step`` recognises
+    it and, in place of a gradient of the arenas, hands the pooled
+    lookups' gradients to ``repro.embedding.sharded.rowwise_adagrad_rows``,
+    which updates the looked-up rows in place and keeps no dense
+    gradient.  Its state is one f32 accumulator per arena row."""
+    lr: float
+    eps: float = 1e-8
+
+    def init(self, params):
+        return OptState(jnp.zeros((), jnp.int32), jax.tree.map(
+            lambda p: jnp.zeros(p.shape[:-1], jnp.float32), params))
+
+
 def apply_updates(params, updates):
     return jax.tree.map(lambda p, u: p + u.astype(p.dtype), params, updates)

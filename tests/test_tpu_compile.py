@@ -88,3 +88,137 @@ def test_lookup_backward_compiles_for_v5e(one_chip, rows, batch, dim, dtype):
     slots = batch * k * pool
     budget = slots * dim * 4 + 4 * slots * 4
     assert compiled.memory_analysis().temp_size_in_bytes <= budget
+
+
+def _dcnv2_step(config, sharding):
+    """The cell's DLRM-DCNv2 step (``bench/configs/dlrm_dcnv2.json``) on
+    its normal path, and its argument shapes on ``sharding``."""
+    import json
+    import numpy as np
+    from repro.core import features as F
+    from repro.embedding.plan import build_plan
+    from repro.models import dlrm
+    from repro.optim import RowWiseAdagrad, adam
+    with open(config) as f:
+        c = json.load(f)
+    rows = np.asarray(c["num_embeddings_per_feature"], np.float64)
+    raw = np.zeros((rows.shape[0], F.NUM_FEATURES))
+    raw[:, F.DIM], raw[:, F.HASH_SIZE] = c["embedding_dim"], rows
+    plan = build_plan(raw, np.zeros(rows.shape[0], int), 1,
+                      pad_dim_to=c["embedding_dim"],
+                      widths=c["multi_hot_sizes"],
+                      pad_rows_to=c["pad_rows_to"])
+    cfg = dlrm.DLRMConfig(
+        n_dense_features=c["num_dense_features"], embed_dim=plan.dim,
+        bottom_mlp=tuple(c["dense_arch_layer_sizes"][:-1]),
+        top_mlp=tuple(c["over_arch_layer_sizes"][:-1]),
+        n_tables=rows.shape[0], interaction=c["interaction_type"],
+        cross_layers=c["dcn_num_layers"], cross_rank=c["dcn_low_rank_dim"])
+    model = dlrm.DLRM(cfg, plan, dtype=jnp.bfloat16)
+    emb_opt = RowWiseAdagrad(c["emb_optimizer"]["lr"])
+    dense_opt = adam(c["dense_optimizer"]["lr"])
+    step = dlrm.make_train_step(
+        model, lambda a, b, i: E.lookup_unsharded(a, plan.base_rows, i, plan),
+        emb_opt, dense_opt)
+    p = jax.eval_shape(model.init_params, jax.random.PRNGKey(0))
+    state = (p, jax.eval_shape(emb_opt.init, {"arenas": p["arenas"]}),
+             jax.eval_shape(dense_opt.init,
+                            {k: p[k] for k in cfg.dense_keys}))
+    B = c["batch_size"]
+    batch = {"dense": jax.ShapeDtypeStruct((B, c["num_dense_features"]),
+                                           jnp.float32),
+             "gidx": jax.ShapeDtypeStruct((B, plan.n_cols), jnp.int32),
+             "labels": jax.ShapeDtypeStruct((B,), jnp.float32)}
+    args = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=sharding), (*state, batch))
+    return step, args, plan
+
+
+def test_dcnv2_step_compiles_for_v5e(one_chip):
+    """The dlrm_dcnv2 cell's whole step at its shapes (29.2 M arena rows
+    of 128, batch 8,192, 214 ids a sample): it compiles, arguments and
+    temporaries fit in 14 GB, and no temporary is as large as the arena,
+    so no dense (R, 128) gradient or arena copy is kept; the lookup's
+    forward is XLA's gather and the row update's sums and writes are
+    Pallas kernels."""
+    root = os.path.join(os.path.dirname(__file__), "..")
+    step, args, plan = _dcnv2_step(
+        os.path.join(root, "bench", "configs", "dlrm_dcnv2.json"), one_chip)
+    compiled = jax.jit(step, donate_argnums=(0, 1, 2)).lower(*args).compile()
+    m = compiled.memory_analysis()
+    arena_bytes = plan.rows_max * plan.dim * 2
+    assert m.argument_size_in_bytes + m.temp_size_in_bytes <= 14e9
+    assert m.temp_size_in_bytes < arena_bytes
+    hlo = compiled.as_text()
+    assert "write_rows" in hlo and "sorted_row_sum" in hlo
+    big = {s for s in re.findall(r"\b(f32|bf16|s32)\[(\d+),128\]", hlo)
+           if int(s[1]) >= plan.rows_max}
+    assert big <= {("bf16", str(plan.rows_max))}, big
+
+
+# sha256 of the dlrm50 cell's step lowered for a described v5e, with each
+# Pallas kernel's serialized body replaced by its text without source
+# locations (``_lowered_without_locations``): the parent of the change
+# that added the DCN-v2 interaction, bag widths and the row update
+DLRM50_LOWERED_SHA256 = ("66a4de5971c507148c758cad3cd154b7"
+                         "9552c777d7f71c6b209bc06179263efe")
+
+
+def _lowered_without_locations(text: str) -> str:
+    """Lowered StableHLO text with every Mosaic kernel body decoded and
+    printed without debug info, so that moving a kernel's source lines
+    leaves it unchanged."""
+    import base64
+    import json
+    from jax._src.lib.mlir import ir
+
+    def body(m):
+        cfg = json.loads(m.group(1).replace("\\22", '"'))
+        raw = base64.b64decode(cfg["custom_call_config"]["body"])
+        ctx = ir.Context()
+        ctx.allow_unregistered_dialects = True
+        with ctx:
+            cfg["custom_call_config"]["body"] = ir.Module.parse(
+                raw).operation.get_asm(enable_debug_info=False)
+        return "backend_config = " + json.dumps(cfg, sort_keys=True)
+
+    return re.sub(r'backend_config = "(\{.*?\})"', body, text)
+
+
+def dlrm50_lowered(sharding) -> str:
+    """The dlrm50 cell's step, as ``bench/program.py`` builds it, lowered
+    for ``sharding``'s device."""
+    import json
+    import sys
+    root = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from bench import program
+    from bench.pool import make_pool
+    from repro.models.dlrm import DENSE_PARAMS, make_train_step
+    with open(os.path.join(root, "bench", "configs", "dlrm50.json")) as f:
+        cfg = json.load(f)
+    pool, _ = make_pool(cfg["pool"]["n_tables"], cfg["pool"]["seed"])
+    raw = pool[:cfg["n_tables"]]
+    prog = program.build(raw, program.place(raw, cfg), cfg, None)
+    step = jax.jit(make_train_step(prog.model, prog.lookup, prog.emb_opt,
+                                   prog.dense_opt), donate_argnums=(0, 1, 2))
+    p = jax.eval_shape(prog.model.init_params, jax.random.PRNGKey(0))
+    es = jax.eval_shape(prog.emb_opt.init, {"arenas": p["arenas"]})
+    ds = jax.eval_shape(prog.dense_opt.init, {k: p[k] for k in DENSE_PARAMS})
+    B, S, K = cfg["batch"], prog.plan.n_shards, prog.plan.k_max
+    batch = {"dense": jax.ShapeDtypeStruct((B, 13), jnp.float32),
+             "gidx": jax.ShapeDtypeStruct((B, S * K, cfg["max_pooling"]),
+                                          jnp.int32),
+             "labels": jax.ShapeDtypeStruct((B,), jnp.float32)}
+    args = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=sharding), (p, es, ds, batch))
+    return step.lower(*args).as_text()
+
+
+def test_dlrm50_lowered_step_is_unchanged(one_chip):
+    """The dlrm50 cell's step, lowered for a v5e, is the one the DCN-v2
+    change started from, apart from its kernels' source locations."""
+    import hashlib
+    text = _lowered_without_locations(dlrm50_lowered(one_chip))
+    assert hashlib.sha256(text.encode()).hexdigest() == DLRM50_LOWERED_SHA256
