@@ -11,7 +11,20 @@ the backward all-to-all.
 
 Inside the ``shard_map`` the lookup is XLA's gather plus a masked pooled
 sum, on every backend; the Pallas forward kernel
-(``repro.kernels.embedding_bag.kernel``) is not on this path.
+(``repro.kernels.embedding_bag.kernel``) is not on this path.  An arena
+narrower than the 128 lanes, whose width D divides them, is gathered by
+row group: the lookup first regroups it into a lane-dense
+``(ceil(R / g), 128)`` view, ``g = 128 // D`` rows a group
+(``REGROUP_SCOPE``; on a TPU the Pallas kernel
+``repro.kernels.embedding_bag.regroup``, elsewhere a pad and reshape),
+since a TPU lays an (R, D) arena out as its (D, R) transpose and a
+gather of single rows reads one lane column at a time.  It gathers each
+slot's group, keeps the D lanes of the slot's own row (and of live slots
+only), sums each bag over its slots in f32 and then folds the g lane
+groups to D.  The other rows' lanes add exact zeros, so a bag sums the
+per-row gather's f32 terms (in another order); the telemetry counter
+``LANE_GROUPED_COUNTER`` counts the shard lookups traced this way.  At D = 128, or a D that does not divide
+128, the lookup gathers single rows.
 
 Its backward is a custom VJP that keeps only the indices.  It sorts the
 shard's slots by arena row, with padded slots sent past the last row
@@ -51,11 +64,15 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
+from repro import telemetry
 from repro.embedding.plan import PlacementPlan
 from repro.kernels.embedding_bag import backward as bwd_kernel
+from repro.kernels.embedding_bag import regroup as regroup_kernel
 from repro.kernels.embedding_bag import row_update as row_kernel
 
 LOOKUP_SCOPE = "emb.lookup"
+REGROUP_SCOPE = "emb.lookup.regroup"
+LANE_GROUPED_COUNTER = "emb.lookup.lane_grouped"
 EXCHANGE_SCOPE = "emb.exchange"
 BWD_SORT_SCOPE = "emb.bwd.sort"
 BWD_FETCH_SCOPE = "emb.bwd.fetch"
@@ -102,6 +119,8 @@ def _local_lookup(arena, bases, idx, col_slot=None):
 
     Padded slots (-1) add nothing whatever arena row 0 holds, and get no
     gradient, so training leaves row 0 zero."""
+    if _lane_group(arena.shape[1]) > 1:
+        telemetry.count(LANE_GROUPED_COUNTER)
     with jax.named_scope(LOOKUP_SCOPE):
         if col_slot is None:
             return _lookup(arena.shape, arena.dtype, arena, bases, idx)
@@ -109,12 +128,58 @@ def _local_lookup(arena, bases, idx, col_slot=None):
             int(k) for k in col_slot), arena, bases, idx)
 
 
+def _lane_group(dim: int) -> int:
+    """Arena rows to one 128-lane row group: ``128 // dim`` where ``dim``
+    is narrower than the lanes and divides them, else 1 (single rows)."""
+    lanes = regroup_kernel.LANES
+    return lanes // dim if dim < lanes and lanes % dim == 0 else 1
+
+
+def _row_groups(arena):
+    """(R, D) -> the lane-dense (ceil(R / g), 128) view of the arena."""
+    with jax.named_scope(REGROUP_SCOPE):
+        return jax.lax.platform_dependent(
+            arena, tpu=lambda a: regroup_kernel.row_groups(a.T),
+            default=regroup_kernel.row_groups_ref)
+
+
+def _gather(arena, keys):
+    """The arena's rows at ``keys`` (...): (..., D); for a narrow arena
+    (``_lane_group``) each key's 128-lane row group, (..., 128)."""
+    g = _lane_group(arena.shape[1])
+    if g == 1:
+        return jnp.take(arena, keys, axis=0)
+    return jnp.take(_row_groups(arena), keys // g, axis=0)
+
+
+def _mask(rows, keys, live, dim):
+    """``_gather``'s rows in f32, zero where not ``live``, and in a row
+    group every lane but the key's own row's zero.  Row groups stay
+    (..., 128) until ``_fold``, after pooling: reshaping every gathered
+    row to (g, D) would be a relayout of all of them."""
+    g = rows.shape[-1] // dim
+    live = live[..., None]
+    if g > 1:
+        live = live & (jnp.arange(rows.shape[-1], dtype=keys.dtype) // dim
+                       == (keys % g)[..., None])
+    return jnp.where(live, rows, 0).astype(jnp.float32)
+
+
+def _fold(pooled, dim):
+    """(..., 128) pooled row groups -> (..., dim): the lane groups
+    summed; (..., dim) unchanged."""
+    g = pooled.shape[-1] // dim
+    if g == 1:
+        return pooled
+    return pooled.reshape(*pooled.shape[:-1], g, dim).sum(-2)
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
 def _lookup(shape, dtype, arena, bases, idx):
     live = idx >= 0
-    rows = jnp.take(arena, jnp.where(live, idx + bases[None, :, None], 0),
-                    axis=0)                                # (B, K, P, D)
-    return jnp.where(live[..., None], rows, 0).astype(jnp.float32).sum(2)
+    keys = jnp.where(live, idx + bases[None, :, None], 0)
+    rows = _gather(arena, keys)                    # (B, K, P, D or 128)
+    return _fold(_mask(rows, keys, live, shape[1]).sum(2), shape[1])
 
 
 def _lookup_fwd(shape, dtype, arena, bases, idx):
@@ -180,10 +245,10 @@ def _col_keys(bases, idx, col_slot, n_rows):
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
 def _lookup_cols(shape, dtype, col_slot, arena, bases, idx):
-    rows = jnp.take(arena, _col_keys(bases, idx, col_slot, 0),
-                    axis=0)                                 # (B, W, D)
-    rows = jnp.where((idx >= 0)[..., None], rows, 0).astype(jnp.float32)
-    return _pool_cols(rows, col_slot, bases.shape[0])
+    keys = _col_keys(bases, idx, col_slot, 0)
+    rows = _gather(arena, keys)                    # (B, W, D or 128)
+    rows = _mask(rows, keys, idx >= 0, shape[1])
+    return _fold(_pool_cols(rows, col_slot, bases.shape[0]), shape[1])
 
 
 def _pool_cols(rows, col_slot, k_slots):

@@ -72,8 +72,8 @@ def _lower_dlrm(mesh, rules, batch=65536, n_tables=160, pool_slots=16):
     embedding (shard_map + all-to-all), DreamShard-style placement plan.
 
     Arenas are stored at the native dim (16): padded to 128 lanes they
-    would take 8x the HBM.  The step looks rows up with XLA's gather
-    (``embedding/sharded.py``), not the Pallas kernel.  Hash sizes
+    would take 8x the HBM.  The step looks rows up with XLA's gather of
+    128-lane row groups (``embedding/sharded.py``).  Hash sizes
     are clipped to 4e6 rows so the 160-table pool fits a v5e-16 shard
     budget (the paper's 11 GB GPUs hold ~20-80 tables per device)."""
     import numpy as np

@@ -14,6 +14,7 @@ import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -90,6 +91,44 @@ def test_lookup_backward_compiles_for_v5e(one_chip, rows, batch, dim, dtype):
     assert compiled.memory_analysis().temp_size_in_bytes <= budget
 
 
+def test_dlrm50_forward_gathers_row_groups_for_v5e(one_chip):
+    """The dlrm50 cell's lookup forward, four shards at its widths
+    (20,512,829 bf16 rows of 16 a shard, batch 65,536, 13 bags of 16):
+    one gather a shard, of 128-lane row groups, with temporaries no
+    larger than the per-row gather's; and the D-128 column lookup keeps
+    gathering single rows, with no regroup."""
+    from test_lane_grouped_lookup import per_row
+    S, R, B, K, P, D = 4, 20_512_829, 65536, 13, 16, 16
+    args = (jax.ShapeDtypeStruct((S, R, D), jnp.bfloat16, sharding=one_chip),
+            jax.ShapeDtypeStruct((S, K), jnp.int32, sharding=one_chip),
+            jax.ShapeDtypeStruct((B, S * K, P), jnp.int32, sharding=one_chip))
+
+    def forward(lookup):
+        return jax.jit(lambda a, b, i: jnp.concatenate(
+            [lookup(a[s], b[s], i[:, s * K:(s + 1) * K]) for s in range(S)],
+            axis=1)).lower(*args).compile()
+
+    grouped, plain = forward(E._local_lookup), forward(per_row)
+    hlo = grouped.as_text()
+    gathers = [line for line in hlo.splitlines() if " gather(" in line]
+    assert len(gathers) == S, gathers
+    assert all("slice_sizes={1,128}" in line for line in gathers), gathers
+    assert E.REGROUP_SCOPE in hlo
+    assert (grouped.memory_analysis().temp_size_in_bytes
+            <= plain.memory_analysis().temp_size_in_bytes)
+
+    col_slot = tuple(np.repeat(np.arange(K), 16))
+    cols = jax.jit(lambda a, b, i: E._local_lookup(a, b, i, col_slot)).lower(
+        jax.ShapeDtypeStruct((1_000_000, 128), jnp.bfloat16,
+                             sharding=one_chip),
+        args[1].update(shape=(K,)),
+        jax.ShapeDtypeStruct((8192, len(col_slot)), jnp.int32,
+                             sharding=one_chip)).compile().as_text()
+    assert E.REGROUP_SCOPE not in cols and "tpu_custom_call" not in cols
+    assert all("slice_sizes={1,128}" in line for line in cols.splitlines()
+               if " gather(" in line)
+
+
 def _dcnv2_step(config, sharding):
     """The cell's DLRM-DCNv2 step (``bench/configs/dlrm_dcnv2.json``) on
     its normal path, and its argument shapes on ``sharding``."""
@@ -158,10 +197,10 @@ def test_dcnv2_step_compiles_for_v5e(one_chip):
 
 # sha256 of the dlrm50 cell's step lowered for a described v5e, with each
 # Pallas kernel's serialized body replaced by its text without source
-# locations (``_lowered_without_locations``): the parent of the change
-# that added the DCN-v2 interaction, bag widths and the row update
-DLRM50_LOWERED_SHA256 = ("66a4de5971c507148c758cad3cd154b7"
-                         "9552c777d7f71c6b209bc06179263efe")
+# locations (``_lowered_without_locations``): the step whose lookup
+# forward gathers 128-lane row groups (``sharded._row_groups``)
+DLRM50_LOWERED_SHA256 = ("9e813547115d86b8e96b3f961177f5d3"
+                         "4c9b1dda52e42bc3721d6fc45aa078f1")
 
 
 def _lowered_without_locations(text: str) -> str:
@@ -217,8 +256,9 @@ def dlrm50_lowered(sharding) -> str:
 
 
 def test_dlrm50_lowered_step_is_unchanged(one_chip):
-    """The dlrm50 cell's step, lowered for a v5e, is the one the DCN-v2
-    change started from, apart from its kernels' source locations."""
+    """The dlrm50 cell's step, lowered for a v5e, is the one pinned
+    above, apart from its kernels' source locations: a change to the
+    step updates the digest on purpose."""
     import hashlib
     text = _lowered_without_locations(dlrm50_lowered(one_chip))
     assert hashlib.sha256(text.encode()).hexdigest() == DLRM50_LOWERED_SHA256
