@@ -1,41 +1,1 @@
-"""Registry of assigned architectures (public-literature pool) + the
-paper's own DLRM recommender (see ``repro.configs.dlrm``).
-"""
-
-from repro.configs import (
-    dbrx_132b, granite_34b, h2o_danube_1p8b, hymba_1p5b, llava_next_34b,
-    musicgen_large, olmoe_1b_7b, phi4_mini_3p8b, qwen2p5_14b, rwkv6_1p6b,
-)
-from repro.configs.shapes import INPUT_SHAPES, InputShape, input_specs  # noqa: F401
-
-_MODULES = {
-    "llava-next-34b": llava_next_34b,
-    "hymba-1.5b": hymba_1p5b,
-    "qwen2.5-14b": qwen2p5_14b,
-    "dbrx-132b": dbrx_132b,
-    "granite-34b": granite_34b,
-    "phi4-mini-3.8b": phi4_mini_3p8b,
-    "olmoe-1b-7b": olmoe_1b_7b,
-    "rwkv6-1.6b": rwkv6_1p6b,
-    "h2o-danube-1.8b": h2o_danube_1p8b,
-    "musicgen-large": musicgen_large,
-}
-
-ARCH_NAMES = tuple(_MODULES)
-
-# sub-quadratic archs that can serve the 524k-token decode shape
-LONG_CONTEXT_ARCHS = ("rwkv6-1.6b", "hymba-1.5b", "h2o-danube-1.8b")
-
-
-def get_full(name: str):
-    return _MODULES[name].FULL
-
-
-def get_smoke(name: str):
-    return _MODULES[name].SMOKE
-
-
-def supports_shape(name: str, shape_name: str) -> bool:
-    if shape_name == "long_500k":
-        return name in LONG_CONTEXT_ARCHS
-    return True
+"""Configurations of the placed DLRM recommender (``repro.configs.dlrm``)."""

@@ -1,9 +1,9 @@
 """DLRM recommender -- the paper's own architecture [arXiv:1906.00091,
 Meta DLRM; table statistics follow the open-sourced DLRM dataset, App. C].
 
-Unlike the LM pool, DLRM's placement-relevant inputs are the embedding
-tables themselves; its dry-run shape is one training step at production
-batch 65536 with DreamShard-placed tables on the model axis.
+Its placement-relevant inputs are the embedding tables themselves; its
+dry-run shape is one training step at production batch 65536 with
+DreamShard-placed tables on the model axis.
 """
 
 from repro.models.dlrm import DLRMConfig

@@ -1,5 +1,5 @@
-"""Training data pipelines: deterministic, seekable synthetic streams for
-LM and DLRM training, with background host prefetch.
+"""Training data pipeline: a deterministic, seekable synthetic stream for
+DLRM training, with background host prefetch.
 
 Production input pipelines are keyed by (shard, step) so any step is
 reproducible and restartable from a checkpointed step counter -- the same
@@ -14,52 +14,6 @@ import threading
 import numpy as np
 
 from repro.core import features as F
-
-
-class LMBatchStream:
-    """Synthetic token batches with a zipf unigram distribution.
-
-    Yields dicts matching ``configs.shapes.input_specs`` for train shapes.
-    """
-
-    def __init__(self, vocab: int, batch: int, seq: int,
-                 n_frontend_tokens: int = 0, d_model: int = 0,
-                 seed: int = 0, zipf_a: float = 1.3):
-        self.vocab = vocab
-        self.batch = batch
-        self.seq = seq
-        self.nf = n_frontend_tokens
-        self.d_model = d_model
-        self.seed = seed
-        self.zipf_a = zipf_a
-
-    def batch_at(self, step: int) -> dict:
-        rng = np.random.default_rng((self.seed, step))
-        n_text = self.seq - self.nf
-        tokens = rng.zipf(self.zipf_a, size=(self.batch, n_text + 1))
-        tokens = (tokens % self.vocab).astype(np.int32)
-        out = {
-            "tokens": tokens[:, :-1],
-            # next-token labels over the full stream (frontend positions
-            # are masked out)
-            "labels": np.concatenate(
-                [np.zeros((self.batch, self.nf), np.int32),
-                 tokens[:, 1:]], axis=1),
-            "loss_mask": np.concatenate(
-                [np.zeros((self.batch, self.nf), np.float32),
-                 np.ones((self.batch, n_text), np.float32)], axis=1),
-        }
-        if self.nf:
-            out["embeds"] = rng.normal(
-                0, 0.02, (self.batch, self.nf, self.d_model)
-            ).astype(np.float32)
-        return out
-
-    def __iter__(self):
-        step = 0
-        while True:
-            yield self.batch_at(step)
-            step += 1
 
 
 class DLRMBatchStream:

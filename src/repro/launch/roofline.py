@@ -1,14 +1,11 @@
 """Roofline term extraction from compiled dry-run artifacts.
 
-Terms per (arch, shape, mesh), all per-device on TPU v5e constants:
+Terms per compiled step, all per-device on TPU v5e constants:
 
   compute_s    = HLO_FLOPs / peak_FLOPs          (197 TF bf16/chip)
   memory_s     = HLO_bytes / HBM_bw              (819 GB/s)
   collective_s = wire_bytes / link_bw            (~50 GB/s/link ICI)
 
-`cost_analysis()` counts a `lax.scan` body once, so the driver compiles
-unrolled 1-layer and 2-layer variants of the same step and extrapolates
-metric(L) = m(1) + (L-1) * (m(2) - m(1)) -- exact for homogeneous stacks.
 Collective wire bytes come from parsing the post-SPMD HLO text: every
 all-gather / all-reduce / reduce-scatter / all-to-all / collective-permute
 result shape, converted to per-device ring-wire bytes via its
@@ -91,7 +88,7 @@ class RooflineTerms:
     hlo_bytes: float            # per device
     wire_bytes: float           # per device
     wire_by_kind: dict
-    model_flops: float          # global analytic 6*N*D
+    model_flops: float          # global analytic FLOPs (0: none known)
     n_devices: int
 
     @property
@@ -131,22 +128,3 @@ class RooflineTerms:
             "useful_flops_ratio": self.useful_flops_ratio,
             "n_devices": self.n_devices,
         }
-
-
-def extrapolate(m1: float, m2: float, n_layers: int) -> float:
-    return m1 + (n_layers - 1) * (m2 - m1)
-
-
-def model_flops(cfg, shape) -> float:
-    """6*N*D (dense) / 6*N_active*D (MoE); decode processes B tokens."""
-    n = cfg.active_param_count()
-    if shape.kind == "train":
-        tokens = shape.global_batch * shape.seq_len
-        mult = 6.0
-    elif shape.kind == "prefill":
-        tokens = shape.global_batch * shape.seq_len
-        mult = 2.0
-    else:
-        tokens = shape.global_batch
-        mult = 2.0
-    return mult * n * tokens

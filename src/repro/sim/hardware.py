@@ -9,8 +9,8 @@ scale (Table 6), with fused-op speedups in the paper's observed 1-3x band
 (Fig. 12) and all-to-all congestion matching Table 4's imbalance behaviour.
 
 A TPU-v5e spec is provided for the TPU-target experiments: 819 GB/s HBM,
-~50 GB/s/link ICI, 197 TFLOP/s bf16 (the roofline constants used by
-``launch/dryrun.py`` as well).
+~50 GB/s/link ICI, 197 TFLOP/s bf16 (the roofline constants of
+``launch/roofline.py`` as well).
 """
 
 from __future__ import annotations

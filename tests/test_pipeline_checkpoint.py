@@ -8,28 +8,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.checkpoint import restore_pytree, save_pytree
-from repro.data.pipeline import DLRMBatchStream, LMBatchStream, Prefetcher
-
-
-def test_lm_stream_deterministic_and_seekable():
-    s = LMBatchStream(vocab=1000, batch=4, seq=32, seed=7)
-    b1 = s.batch_at(13)
-    b2 = LMBatchStream(vocab=1000, batch=4, seq=32, seed=7).batch_at(13)
-    np.testing.assert_array_equal(b1["tokens"], b2["tokens"])
-    assert b1["tokens"].shape == (4, 32)
-    assert (b1["tokens"] < 1000).all() and (b1["tokens"] >= 0).all()
-    # labels are next-token shifted
-    assert b1["labels"].shape == (4, 32)
-
-
-def test_lm_stream_frontend_masks_loss():
-    s = LMBatchStream(vocab=100, batch=2, seq=16, n_frontend_tokens=4,
-                      d_model=8, seed=0)
-    b = s.batch_at(0)
-    assert b["tokens"].shape == (2, 12)
-    assert b["embeds"].shape == (2, 4, 8)
-    assert (b["loss_mask"][:, :4] == 0).all()
-    assert (b["loss_mask"][:, 4:] == 1).all()
+from repro.data.pipeline import DLRMBatchStream, Prefetcher
 
 
 def test_dlrm_stream_respects_hash_bounds(dlrm_pool):
@@ -41,15 +20,18 @@ def test_dlrm_stream_respects_hash_bounds(dlrm_pool):
         assert (live < dlrm_pool[t, 1]).all()
 
 
-def test_prefetcher_matches_direct():
-    s = LMBatchStream(vocab=100, batch=2, seq=8, seed=1)
+def test_prefetcher_matches_direct(dlrm_pool):
+    s = DLRMBatchStream(dlrm_pool[:4], batch=2, seed=1)
     p = Prefetcher(s, depth=2)
     try:
         got = [p.next() for _ in range(3)]
     finally:
         p.close()
     for i, b in enumerate(got):
-        np.testing.assert_array_equal(b["tokens"], s.batch_at(i)["tokens"])
+        want = s.batch_at(i)
+        assert b.keys() == want.keys()
+        for k in want:
+            np.testing.assert_array_equal(b[k], want[k])
 
 
 def test_checkpoint_roundtrip_mixed_dtypes():
@@ -66,19 +48,44 @@ def test_checkpoint_roundtrip_mixed_dtypes():
     assert int(out["step"]) == 7
 
 
-def test_checkpoint_model_params_roundtrip():
-    from repro import configs as C
-    from repro.launch import steps as ST
-    cfg = C.get_smoke("qwen2.5-14b").resolve(1)
-    model = ST.build_model(cfg, remat=False)
+def test_checkpoint_model_params_roundtrip(dlrm_pool):
+    """The placed DLRM's parameters (bf16 arenas, f32 dense nets) and
+    both optimizer states, after one step so that none is all zeros."""
+    from repro.core import features as F
+    from repro.embedding import sharded as E
+    from repro.embedding.plan import build_plan
+    from repro.models.dlrm import DLRM, DLRMConfig, make_train_step
+    from repro.optim import adam, rowwise_adagrad
+    raw = dlrm_pool[:6].copy()
+    raw[:, F.HASH_SIZE] = np.clip(raw[:, F.HASH_SIZE], 0, 300)
+    plan = build_plan(raw, np.arange(6) % 2, 2, pad_dim_to=16)
+    model = DLRM(DLRMConfig(n_dense_features=4, embed_dim=plan.dim,
+                            bottom_mlp=(8,), top_mlp=(8,), n_tables=6),
+                 plan)
     params = model.init_params(jax.random.PRNGKey(0))
+    params["arenas"] = params["arenas"].astype(jnp.bfloat16)
+    emb_opt, dense_opt = rowwise_adagrad(0.05), adam(1e-3)
+    step = make_train_step(
+        model, lambda a, b, i: E.lookup_unsharded(a, b, i, plan), emb_opt,
+        dense_opt)
+    b = DLRMBatchStream(raw, batch=4, n_dense=4, pool_slots=3).batch_at(0)
+    batch = {"dense": b["dense"], "labels": b["labels"],
+             "gidx": E.group_indices(plan, b["indices"])}
+    params, es, ds, _ = jax.jit(step)(
+        params, emb_opt.init({"arenas": params["arenas"]}),
+        dense_opt.init({k: params[k] for k in model.cfg.dense_keys}), batch)
+    tree = {"params": params, "emb": es, "dense": ds}
+    assert int(es.step) == int(ds.step) == 1
+    assert params["arenas"].dtype == jnp.bfloat16
     with tempfile.TemporaryDirectory() as d:
-        save_pytree(params, os.path.join(d, "ckpt"))
-        out = restore_pytree(jax.tree.map(jnp.zeros_like, params),
+        save_pytree(tree, os.path.join(d, "ckpt"))
+        out = restore_pytree(jax.tree.map(jnp.zeros_like, tree),
                              os.path.join(d, "ckpt"))
-    for a, b in zip(jax.tree.leaves(params), jax.tree.leaves(out)):
-        np.testing.assert_array_equal(np.asarray(a, np.float32),
-                                      np.asarray(b, np.float32))
+    assert jax.tree.structure(out) == jax.tree.structure(tree)
+    for want, got in zip(jax.tree.leaves(tree), jax.tree.leaves(out)):
+        assert got.dtype == want.dtype and got.shape == want.shape
+        np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                      np.asarray(want, np.float32))
 
 
 def test_dreamshard_agent_checkpoint_roundtrip(dlrm_pool):

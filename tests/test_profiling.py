@@ -172,9 +172,27 @@ def test_v1_artifact_loads_additive_with_warning(synth_table, tmp_path,
     assert v1.fusion_fwd.source == "v1-fallback"
 
 
+@pytest.fixture()
+def scratch_compile_cache(tmp_path, monkeypatch):
+    """The CLI's ``enable_compile_cache`` pointed at a temporary directory,
+    and the process-global cache directory put back afterwards, so no
+    later test in this process compiles through a persistent cache."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+    from repro import compile_cache
+    monkeypatch.delenv(compile_cache.ENV_VAR, raising=False)
+    monkeypatch.setattr(compile_cache, "DEFAULT_DIR",
+                        tmp_path / "jax_cache")
+    was = jax.config.jax_compilation_cache_dir
+    yield
+    jax.config.update("jax_compilation_cache_dir", was)
+    compilation_cache.reset_cache()
+
+
 def test_calibrate_cli_regenerates_v1_artifact(synth_table, tmp_path,
                                                capsys,
-                                               save_v1_calibration):
+                                               save_v1_calibration,
+                                               scratch_compile_cache):
     """An existing artifact that predates schema v2 is re-measured, not
     skipped -- and the refreshed artifact carries a measured fusion fit."""
     out = str(tmp_path / "cal.npz")
@@ -397,6 +415,7 @@ def test_calibrate_cli_smoke(tmp_path):
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src") \
         + os.pathsep + env.get("PYTHONPATH", "")
+    env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / "jax_cache")
     cmd = [sys.executable, "-m", "repro.profiling.calibrate", "--smoke",
            "--out", out, "--repeats", "1",
            "--dims", "16,64", "--rows", "128", "--poolings", "2"]

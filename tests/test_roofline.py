@@ -40,10 +40,6 @@ def test_reduce_scatter_scales_by_group():
     assert wire["reduce-scatter"] == pytest.approx(shard * 16 * 15 / 16)
 
 
-def test_extrapolation():
-    assert R.extrapolate(10.0, 12.0, 48) == pytest.approx(10 + 47 * 2)
-
-
 def test_terms_and_dominant():
     t = R.RooflineTerms(hlo_flops=197e12, hlo_bytes=819e9 * 2,
                         wire_bytes=50e9 * 0.5, wire_by_kind={},
@@ -54,14 +50,3 @@ def test_terms_and_dominant():
     assert t.dominant == "memory"
     assert t.useful_flops_ratio == pytest.approx(0.5)
 
-
-def test_model_flops_kinds():
-    from repro import configs as C
-    from repro.configs.shapes import INPUT_SHAPES
-    cfg = C.get_full("qwen2.5-14b")
-    f_train = R.model_flops(cfg, INPUT_SHAPES["train_4k"])
-    f_decode = R.model_flops(cfg, INPUT_SHAPES["decode_32k"])
-    assert f_train > f_decode
-    # MoE uses active params only
-    moe = C.get_full("olmoe-1b-7b")
-    assert moe.active_param_count() < moe.param_count()

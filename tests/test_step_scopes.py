@@ -99,7 +99,20 @@ def program_scopes_off(names=PROGRAM_SCOPES):
 
 
 @pytest.fixture(scope="module")
-def scoped():
+def no_compile_cache():
+    """The persistent compile cache off: its key leaves out op metadata,
+    so a cached scoped step would come back for the bare one."""
+    from jax.experimental.compilation_cache import compilation_cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def scoped(no_compile_cache):
     return tiny_step_hlo()
 
 
