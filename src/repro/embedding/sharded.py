@@ -11,20 +11,29 @@ the backward all-to-all.
 
 Inside the ``shard_map`` the lookup is XLA's gather plus a masked pooled
 sum, on every backend; the Pallas forward kernel
-(``repro.kernels.embedding_bag.kernel``) is not on this path.  An arena
-narrower than the 128 lanes, whose width D divides them, is gathered by
-row group: the lookup first regroups it into a lane-dense
+(``repro.kernels.embedding_bag.kernel``) is not on this path.  A
+``(B, K, P)`` shard lookup gathers only the index columns (slot k,
+position p) that hold a live id somewhere in the batch: a bag padded to
+P leaves the same trailing columns padding in every sample.  It finds
+the live columns on the device and runs one loop over them
+(``COLUMNS_SCOPE``): each column one gather of B rows, masked and added
+in f32 into its slot's bag.  The loop is traced once, so the program
+does not grow with K or P; the telemetry counter
+``COLUMN_LOOP_COUNTER`` counts the shard lookups traced this way.  An
+arena narrower than the 128 lanes, whose width D divides them, is
+gathered by row group: the lookup first regroups it into a lane-dense
 ``(ceil(R / g), 128)`` view, ``g = 128 // D`` rows a group
 (``REGROUP_SCOPE``; on a TPU the Pallas kernel
 ``repro.kernels.embedding_bag.regroup``, elsewhere a pad and reshape),
 since a TPU lays an (R, D) arena out as its (D, R) transpose and a
 gather of single rows reads one lane column at a time.  It gathers each
 slot's group, keeps the D lanes of the slot's own row (and of live slots
-only), sums each bag over its slots in f32 and then folds the g lane
-groups to D.  The other rows' lanes add exact zeros, so a bag sums the
-per-row gather's f32 terms (in another order); the telemetry counter
-``LANE_GROUPED_COUNTER`` counts the shard lookups traced this way.  At D = 128, or a D that does not divide
-128, the lookup gathers single rows.
+only), sums each bag in f32 and then folds the g lane groups to D.  The
+other rows' lanes add exact zeros, so a bag sums the per-row gather's
+f32 terms (in another order); the telemetry counter
+``LANE_GROUPED_COUNTER`` counts the shard lookups traced this way.  At
+D = 128, or a D that does not divide 128, the lookup gathers single
+rows.
 
 Its backward is a custom VJP that keeps only the indices.  It sorts the
 shard's slots by arena row, with padded slots sent past the last row
@@ -73,6 +82,8 @@ from repro.kernels.embedding_bag import row_update as row_kernel
 LOOKUP_SCOPE = "emb.lookup"
 REGROUP_SCOPE = "emb.lookup.regroup"
 LANE_GROUPED_COUNTER = "emb.lookup.lane_grouped"
+COLUMNS_SCOPE = "emb.lookup.columns"
+COLUMN_LOOP_COUNTER = "emb.lookup.column_loop"
 EXCHANGE_SCOPE = "emb.exchange"
 BWD_SORT_SCOPE = "emb.bwd.sort"
 BWD_FETCH_SCOPE = "emb.bwd.fetch"
@@ -123,6 +134,7 @@ def _local_lookup(arena, bases, idx, col_slot=None):
         telemetry.count(LANE_GROUPED_COUNTER)
     with jax.named_scope(LOOKUP_SCOPE):
         if col_slot is None:
+            telemetry.count(COLUMN_LOOP_COUNTER)
             return _lookup(arena.shape, arena.dtype, arena, bases, idx)
         return _lookup_cols(arena.shape, arena.dtype, tuple(
             int(k) for k in col_slot), arena, bases, idx)
@@ -143,17 +155,23 @@ def _row_groups(arena):
             default=regroup_kernel.row_groups_ref)
 
 
-def _gather(arena, keys):
-    """The arena's rows at ``keys`` (...): (..., D); for a narrow arena
-    (``_lane_group``) each key's 128-lane row group, (..., 128)."""
-    g = _lane_group(arena.shape[1])
-    if g == 1:
-        return jnp.take(arena, keys, axis=0)
-    return jnp.take(_row_groups(arena), keys // g, axis=0)
+def _view(arena):
+    """What the lookup gathers from: a narrow arena's (``_lane_group``)
+    lane-dense row groups, else the arena itself."""
+    if _lane_group(arena.shape[1]) == 1:
+        return arena
+    return _row_groups(arena)
+
+
+def _take(view, keys, dim):
+    """``_view``'s rows at ``keys`` (...): (..., D); for a narrow arena
+    each key's 128-lane row group, (..., 128)."""
+    g = view.shape[1] // dim
+    return jnp.take(view, keys // g if g > 1 else keys, axis=0)
 
 
 def _mask(rows, keys, live, dim):
-    """``_gather``'s rows in f32, zero where not ``live``, and in a row
+    """``_take``'s rows in f32, zero where not ``live``, and in a row
     group every lane but the key's own row's zero.  Row groups stay
     (..., 128) until ``_fold``, after pooling: reshaping every gathered
     row to (g, D) would be a relayout of all of them."""
@@ -176,10 +194,34 @@ def _fold(pooled, dim):
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
 def _lookup(shape, dtype, arena, bases, idx):
-    live = idx >= 0
-    keys = jnp.where(live, idx + bases[None, :, None], 0)
-    rows = _gather(arena, keys)                    # (B, K, P, D or 128)
-    return _fold(_mask(rows, keys, live, shape[1]).sum(2), shape[1])
+    batch, k_slots, pool = idx.shape
+    dim = shape[1]
+    view = _view(arena)
+    cols = idx.reshape(batch, k_slots * pool).T    # (K * P, B)
+    live = jnp.any(cols >= 0, axis=1)
+    order = jnp.argsort(~live, stable=True)        # live columns first
+
+    def add_column(j, bags):
+        c = jax.lax.dynamic_index_in_dim(order, j, keepdims=False)
+        k = c // pool
+        ids = jax.lax.dynamic_index_in_dim(cols, c, keepdims=False)
+        ok = ids >= 0
+        keys = jnp.where(
+            ok, ids + jax.lax.dynamic_index_in_dim(bases, k, keepdims=False),
+            0)
+        rows = _mask(_take(view, keys, dim), keys, ok, dim)   # (B, D or 128)
+        bag = jax.lax.dynamic_index_in_dim(bags, k, keepdims=False)
+        return jax.lax.dynamic_update_index_in_dim(bags, bag + rows, k, 0)
+
+    with jax.named_scope(COLUMNS_SCOPE):
+        bags = jax.lax.fori_loop(
+            0, jnp.sum(live, dtype=jnp.int32), add_column,
+            jnp.zeros((k_slots, batch, view.shape[1]), jnp.float32))
+    # laid out for the loop: without the barrier XLA lays the bags out
+    # batch-minor, as the (B, K, D) result wants, and transposes a slot's
+    # (B, 128) block in and out in every iteration
+    bags = jax.lax.optimization_barrier(bags)
+    return jnp.moveaxis(_fold(bags, dim), 0, 1)
 
 
 def _lookup_fwd(shape, dtype, arena, bases, idx):
@@ -246,7 +288,7 @@ def _col_keys(bases, idx, col_slot, n_rows):
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
 def _lookup_cols(shape, dtype, col_slot, arena, bases, idx):
     keys = _col_keys(bases, idx, col_slot, 0)
-    rows = _gather(arena, keys)                    # (B, W, D or 128)
+    rows = _take(_view(arena), keys, shape[1])     # (B, W, D or 128)
     rows = _mask(rows, keys, idx >= 0, shape[1])
     return _fold(_pool_cols(rows, col_slot, bases.shape[0]), shape[1])
 

@@ -1,9 +1,9 @@
 """The lane-grouped forward of the placed lookup (``embedding.sharded``).
 
 An arena narrower than the 128 lanes, whose width divides them, is
-gathered by 128-lane row groups (``_row_groups`` -> one gather -> each
-slot's own lanes kept -> bag sums -> lane groups folded); any other width
-gathers single rows, with the program it had.  Checked against the
+gathered by 128-lane row groups (``_row_groups`` -> one gather a live
+index column -> each slot's own lanes kept -> bag sums -> lane groups
+folded); any other width gathers single rows of the arena.  Checked against the
 per-row formula (XLA's gather of single rows and a masked f32 sum): the
 plain-JAX regroup and the Pallas kernel in interpret mode, plans of one
 and four shards, the column layout, the custom VJP's gradients and the
@@ -14,6 +14,7 @@ the two summation orders (over the slots, or over each lane group's
 slots and then the groups) give the same bits; a wrong lane or row kept
 or dropped changes them."""
 
+import collections
 import functools
 
 import jax
@@ -140,21 +141,35 @@ def _body(closed_jaxpr):
     return str(closed_jaxpr.jaxpr).split("let", 1)[1]
 
 
+def _primitives(jaxpr) -> collections.Counter:
+    """Primitive name -> count in a jaxpr, nested jaxprs included."""
+    out = collections.Counter()
+    for eqn in jaxpr.eqns:
+        out[eqn.primitive.name] += 1
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            out += _primitives(sub)
+    return out
+
+
 @pytest.mark.parametrize("dim", [128, 48])
 def test_wide_or_non_dividing_dims_keep_the_per_row_path(telemetry, dim):
-    """At D = 128, or a D that does not divide 128, the lookup's program
-    is the per-row formula, equation for equation, and counts nothing."""
+    """At D = 128, or a D that does not divide 128, the lookup gathers
+    single rows of the arena itself: the column loop's one gather takes
+    (1, D) rows of the (R, D) arena, with no regroup, and nothing counts
+    as lane-grouped.  The column lookup's program is the per-row formula,
+    equation for equation."""
     arena = grid_arena(301, dim, seed=1)
     idx, bases = shard_ids(301, 3, 8, 5, seed=1)
+    jaxpr = jax.make_jaxpr(E._local_lookup)(arena, bases, idx).jaxpr
+    assert _gathers(jaxpr) == [((301, dim), (1, dim))]
+    assert not {"platform_index", "pallas_call"} & set(_primitives(jaxpr))
     col_slot = (0, 0, 1, -1, 2, 2)
     cidx = idx[:, :, 0].repeat(2, axis=1)
-    for ours, plain, i in [
-            (E._local_lookup, per_row, idx),
-            (functools.partial(E._local_lookup, col_slot=col_slot),
-             functools.partial(per_row_cols, col_slot=col_slot), cidx)]:
-        (eqn,) = jax.make_jaxpr(ours)(arena, bases, i).jaxpr.eqns
-        assert _body(eqn.params["call_jaxpr"]) == _body(
-            jax.make_jaxpr(plain)(arena, bases, i))
+    (eqn,) = jax.make_jaxpr(functools.partial(
+        E._local_lookup, col_slot=col_slot))(arena, bases, cidx).jaxpr.eqns
+    assert _body(eqn.params["call_jaxpr"]) == _body(jax.make_jaxpr(
+        functools.partial(per_row_cols, col_slot=col_slot))(
+            arena, bases, cidx))
     assert telemetry.counter_value(E.LANE_GROUPED_COUNTER) == 0
 
 

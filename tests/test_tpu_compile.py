@@ -94,8 +94,8 @@ def test_lookup_backward_compiles_for_v5e(one_chip, rows, batch, dim, dtype):
 def test_dlrm50_forward_gathers_row_groups_for_v5e(one_chip):
     """The dlrm50 cell's lookup forward, four shards at its widths
     (20,512,829 bf16 rows of 16 a shard, batch 65,536, 13 bags of 16):
-    one gather a shard, of 128-lane row groups, with temporaries no
-    larger than the per-row gather's; and the D-128 column lookup keeps
+    one gather a shard (in its column loop), of 128-lane row groups,
+    with temporaries no larger than the per-row gather's; and the D-128 column lookup keeps
     gathering single rows, with no regroup."""
     from test_lane_grouped_lookup import per_row
     S, R, B, K, P, D = 4, 20_512_829, 65536, 13, 16, 16
@@ -198,9 +198,10 @@ def test_dcnv2_step_compiles_for_v5e(one_chip):
 # sha256 of the dlrm50 cell's step lowered for a described v5e, with each
 # Pallas kernel's serialized body replaced by its text without source
 # locations (``_lowered_without_locations``): the step whose lookup
-# forward gathers 128-lane row groups (``sharded._row_groups``)
-DLRM50_LOWERED_SHA256 = ("9e813547115d86b8e96b3f961177f5d3"
-                         "4c9b1dda52e42bc3721d6fc45aa078f1")
+# forward gathers 128-lane row groups (``sharded._row_groups``) of the
+# live index columns alone, in one loop (``sharded.COLUMNS_SCOPE``)
+DLRM50_LOWERED_SHA256 = ("a76ea166209e18d24c7b70e7cb5a2e4e"
+                         "d584d23002b0f3ff2f6bfaeaef9af7ab")
 
 
 def _lowered_without_locations(text: str) -> str:
